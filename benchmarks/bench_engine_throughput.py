@@ -149,8 +149,8 @@ def run(backends=("reference", "pallas"), smoke=False):
             # The 1024x1024 bucket is the whole point of this benchmark
             # and is hours-long in interpret mode — kernel rows only make
             # sense compiled (TPU attached).
-            from repro.core.backends.pallas import _default_interpret
-            if _default_interpret():
+            from repro.kernels.banded_dp.banded_dp import default_interpret
+            if default_interpret():
                 # A note, not an emit(): a 0.0-us row would pollute the
                 # machine-readable perf trajectory.
                 print("engine: pallas rows skipped (interpret mode, "

@@ -96,8 +96,8 @@ def run(backends=("reference", "pallas"), smoke=False):
 
     for backend in backends:
         if backend == "pallas":
-            from repro.core.backends.pallas import _default_interpret
-            if _default_interpret():
+            from repro.kernels.banded_dp.banded_dp import default_interpret
+            if default_interpret():
                 print("bench_mapper: skipping pallas rows (interpret "
                       "mode, no TPU)", file=sys.stderr)
                 continue

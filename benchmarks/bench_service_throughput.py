@@ -172,8 +172,8 @@ def run(backends=("reference", "pallas"), smoke=False):
     pairs = _request_pool(n_pairs)
     for backend in backends:
         if backend == "pallas":
-            from repro.core.backends.pallas import _default_interpret
-            if _default_interpret():
+            from repro.kernels.banded_dp.banded_dp import default_interpret
+            if default_interpret():
                 print("service: pallas rows skipped (interpret mode, "
                       "no TPU)", file=sys.stderr)
                 continue

@@ -2,7 +2,8 @@
 
 The TPU compute-memory analogue of the RAPIDx CM array. On CPU hosts the
 kernel runs in interpret mode (bit-exact, for validation); on TPU it
-compiles. `interpret=None` picks automatically from the attached devices.
+compiles. `interpret=None` leaves the choice to the kernel's
+`default_interpret` (compiled exactly when a TPU is attached).
 
 Persistent dispatch (`run_persistent`) stacks every group of a request
 into one uniform (G, nb_max, bt, L_max) layout and launches the
@@ -24,10 +25,6 @@ import numpy as np
 from repro.kernels.banded_dp.ops import banded_align_kernel_batch
 
 
-def _default_interpret() -> bool:
-    return not any(d.platform == "tpu" for d in jax.devices())
-
-
 @dataclasses.dataclass(frozen=True)
 class PallasBackend:
     name = "pallas"
@@ -38,12 +35,10 @@ class PallasBackend:
     def run(self, q_pad, r_pad, n, m, *, sc, band, adaptive=True,
             collect_tb=True, mode="global", t_max=None, decode="host",
             cell_dtype="int32", xdrop=None):
-        interpret = (self.interpret if self.interpret is not None
-                     else _default_interpret())
         out = banded_align_kernel_batch(
             q_pad, r_pad, n, m, sc=sc, band=band, adaptive=adaptive,
             collect_tb=collect_tb, mode=mode, batch_tile=self.batch_tile,
-            chunk=self.chunk, interpret=interpret, t_max=t_max,
+            chunk=self.chunk, interpret=self.interpret, t_max=t_max,
             cell_dtype=cell_dtype, xdrop=xdrop)
         if collect_tb and decode == "device":
             # Apply the lockstep walker to the kernel's TBM block: the
@@ -64,15 +59,14 @@ class PallasBackend:
             raise ValueError(
                 "persistent dispatch fuses the traceback decode on-device;"
                 " decode='host' exists only on the pipelined path")
-        interpret = (self.interpret if self.interpret is not None
-                     else _default_interpret())
         bt = self.batch_tile
         geom = tuple(
             (int(q.shape[1]), int(r.shape[1]), int(band),
              None if t_max is None else int(t_max), int(q.shape[0]))
             for (q, r, n, m, band, t_max) in groups)
         fn = _persistent_program(sc, adaptive, collect_tb, mode, cell_dtype,
-                                 geom, bt, self.chunk, interpret, xdrop)
+                                 geom, bt, self.chunk, self.interpret,
+                                 xdrop)
         return fn(*_stack_groups(groups, geom, bt))
 
 

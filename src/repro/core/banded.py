@@ -139,17 +139,20 @@ def pack_tb_lanes(code):
     returns uint8 of shape ``(..., ceil(B / 2))`` in the low/high-nibble
     layout above. jnp-traceable: this runs inside the reference backend's
     `lax.scan` step and the Pallas kernel's register file, so the unpacked
-    plane never exists in HBM or on the host. Implemented as strided
-    lane slices + shift/or (no reshape that splits the minor axis —
-    the friendlier form for Mosaic's TPU layout rules).
+    plane never exists in HBM or on the host. Implemented with ops the
+    TPU kernel compiler lowers: a one-lane shift pairs each lane with its
+    odd neighbour, then a same-shape lane gather moves lane 2b to lane b
+    (no strided lane slice, no reshape that splits the minor axis).
     """
-    *lead, B = code.shape
-    low = code[..., 0::2].astype(jnp.int32)    # ceil(B/2) even lanes
-    high = code[..., 1::2].astype(jnp.int32)   # floor(B/2) odd lanes
-    if B % 2:  # odd B: the last byte's high nibble is zero padding
-        high = jnp.concatenate(
-            [high, jnp.zeros((*lead, 1), jnp.int32)], axis=-1)
-    return (low | (high << 4)).astype(jnp.uint8)
+    code = code.astype(jnp.int32)
+    B = code.shape[-1]
+    nxt = jnp.concatenate([code[..., 1:], jnp.zeros_like(code[..., :1])],
+                          axis=-1)
+    pair = code | (nxt << 4)   # lane k: flags(k) | flags(k+1) << 4
+    lane = jax.lax.broadcasted_iota(jnp.int32, code.shape, code.ndim - 1)
+    even = jnp.take_along_axis(pair, jnp.minimum(2 * lane, B - 1),
+                               axis=-1)
+    return even[..., :packed_tb_width(B)].astype(jnp.uint8)
 
 
 def select_tb_nibble(byte, lane):

@@ -19,20 +19,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-try:  # jax >= 0.6 exports shard_map at top level (kwarg: check_vma)
-    from jax import shard_map as _shard_map_impl
-    _REP_KWARG = "check_vma"
-except ImportError:  # older jax: experimental module (kwarg: check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-    _REP_KWARG = "check_rep"
-
 from repro.core.scoring import ScoringConfig, MINIMAP2
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shard_map with replication checking disabled."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **{_REP_KWARG: False})
+    """`jax.shard_map` with replication checking disabled."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_aligner(mesh: Mesh, sc: ScoringConfig = MINIMAP2, *, band: int,

@@ -44,6 +44,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
+import pathlib
 
 import numpy as np
 
@@ -135,31 +137,38 @@ class PendingPersistent:
             for grp in self.batch)
 
 
-def _enable_compilation_cache(cache_dir: str) -> None:
-    """Point JAX's persistent compilation cache at `cache_dir` and make
-    every dispatch-signature program eligible for it (the default
-    thresholds skip sub-second compiles — exactly the many small
-    per-signature programs a serving replica pays at traffic time).
-    Flags that this JAX version does not know are skipped."""
+#: The compile cache's home when JAX_COMPILATION_CACHE_DIR is unset: one
+#: fixed directory inside the checkout (git-ignored). The path is part of
+#: the cache key, so a directory that moved between runs would never hit.
+CHECKOUT_CACHE_DIR = str(pathlib.Path(__file__).resolve().parents[3]
+                         / ".jax_cache")
+
+
+def enable_compilation_cache(cache_dir: str | None = None) -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    `JAX_COMPILATION_CACHE_DIR`, when set, wins: JAX already reads it, and
+    no other directory is set here. Otherwise the cache goes to
+    `cache_dir`, or to `CHECKOUT_CACHE_DIR` when that is None. Every
+    dispatch-signature program is made eligible (the default thresholds
+    skip sub-second compiles — exactly the many small per-signature
+    programs a serving replica pays at traffic time), and the process's
+    cache handle is re-initialised, since the first compile may have
+    happened before this call with caching still off.
+    """
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    for flag, value in (("jax_compilation_cache_dir", cache_dir),
-                        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(flag, value)
-        except (AttributeError, ValueError):  # older/newer jax: best effort
-            pass
-    try:
-        # The cache handle is initialised once per process, on the first
-        # compile — which may have happened before this engine existed
-        # (with caching then silently off). Re-initialise it against the
-        # directory just configured.
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:  # noqa: BLE001 — private API: best effort only
-        pass
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        cache_dir = env_dir
+    else:
+        cache_dir = cache_dir or CHECKOUT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    compilation_cache.reset_cache()
+    return cache_dir
 
 
 def _check_t_max(t_max, n, m) -> None:
@@ -244,14 +253,14 @@ class AlignmentEngine:
       batch_axes: mesh axes to shard over; None = every axis named
         "pod"/"data" in the mesh (alignment never uses "model").
       compilation_cache_dir: when set, wire JAX's persistent
-        compilation cache to this directory (and drop the min-compile-
-        time / min-entry-size persistence thresholds so the dispatch
-        programs always persist). A replica restarted against a warm
-        cache deserialises its dispatch signatures instead of
-        recompiling them — pair with `warmup()` so the deserialisation
-        happens before traffic arrives. The flag is process-global in
-        JAX; constructing two engines with different directories moves
-        the cache for both.
+        compilation cache to this directory through
+        `enable_compilation_cache`, which yields to
+        JAX_COMPILATION_CACHE_DIR when that is set. A replica restarted
+        against a warm cache deserialises its dispatch signatures
+        instead of recompiling them — pair with `warmup()` so the
+        deserialisation happens before traffic arrives. The flag is
+        process-global in JAX; constructing two engines with different
+        directories moves the cache for both.
     """
 
     backend: object = "auto"
@@ -273,7 +282,7 @@ class AlignmentEngine:
 
     def __post_init__(self):
         if self.compilation_cache_dir is not None:
-            _enable_compilation_cache(self.compilation_cache_dir)
+            enable_compilation_cache(self.compilation_cache_dir)
         self.backend = get_backend(self.backend,
                                    **(self.backend_opts or {}))
         if self.dispatch not in ("pipelined", "persistent"):
@@ -646,5 +655,5 @@ class AlignmentEngine:
 
 
 __all__ = ["AlignmentEngine", "PendingDispatch", "PendingPersistent",
-           "SCALAR_KEYS", "available_backends", "get_backend",
+           "SCALAR_KEYS", "enable_compilation_cache", "available_backends", "get_backend",
            "resolve_backend", "run_dispatch"]

@@ -6,8 +6,15 @@ band offset — lives in **VMEM scratch for the entire sweep**, exactly as
 RAPIDx keeps it resident in the ReRAM subarray ("in-situ alignment", §V-C).
 Sequences stream in once; only the 4-bit traceback flags stream out to HBM
 (the TBM analogue). Per wavefront step the kernel does a handful of 8x128
-VPU vector ops — the row-parallel PIM operations — plus two small gathers
-for the moving sequence window (the peripheral *shifter*).
+VPU vector ops — the row-parallel PIM operations. The sequence bases under
+the band ride in the carry as two B-lane windows moved by the same one-lane
+shifts as the band state (the peripheral *shifter*): a down step keeps
+every lane's j and shifts the query window by one lane, a right step keeps
+i and shifts the reference window the other way, so each step one new base
+enters one window per pair. The entering bases come from a per-chunk
+128-lane strip of each sequence, cut once per step chunk at each pair's
+own offset (`_seq_strip`). Every vector op is one Mosaic lowers: lane
+shifts, selects, lane reductions and same-shape lane gathers.
 
 Parallelism mapping (paper Fig. 6):
   * wavefront level  -> lane dimension (band B, up to 128 lanes)
@@ -26,6 +33,9 @@ traceback plane is packed **two 4-bit flags per uint8 byte** in-register
 before the TBM store (`core.banded.pack_tb_lanes` layout: even lane in the
 low nibble), so the per-step store is ceil(B/2) bytes per pair — half the
 TBM traffic of a one-flag-per-byte plane. See DESIGN.md §5/§6.
+
+`interpret=None` (the default of every entry point) resolves in one
+place, `default_interpret`: compiled on a TPU, interpreted elsewhere.
 """
 
 from __future__ import annotations
@@ -42,16 +52,99 @@ from repro.core.scoring import ScoringConfig
 
 NEG = -(1 << 28)   # plain ints: pallas kernels must not capture jax arrays
 DEAD = -(1 << 27)
+_I32_MIN = -(1 << 31)
+
+#: Lanes per sequence strip (one vreg row): the bases a pair's windows can
+#: take in over one step chunk. Chunks are capped at this many steps.
+STRIP = 128
+
+
+def default_interpret() -> bool:
+    """Interpret the kernels unless a TPU is attached (the one place the
+    `interpret=None` default of the kernel entry points resolves)."""
+    return jax.devices()[0].platform != "tpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """`interpret`, or the platform default when it is None."""
+    return default_interpret() if interpret is None else bool(interpret)
 
 
 def _shift_toward_lane0(a, fill):
-    """result[:, k] = a[:, k+1]; last lane <- fill."""
-    return jnp.concatenate([a[:, 1:], jnp.full_like(a[:, :1], fill)], axis=1)
+    """result[:, k] = a[:, k+1]; last lane <- fill (scalar or (bt, 1))."""
+    col = jnp.broadcast_to(jnp.asarray(fill, a.dtype), a[:, :1].shape)
+    return jnp.concatenate([a[:, 1:], col], axis=1)
 
 
 def _shift_away_lane0(a, fill):
-    """result[:, k] = a[:, k-1]; lane 0 <- fill."""
-    return jnp.concatenate([jnp.full_like(a[:, :1], fill), a[:, :-1]], axis=1)
+    """result[:, k] = a[:, k-1]; lane 0 <- fill (scalar or (bt, 1))."""
+    col = jnp.broadcast_to(jnp.asarray(fill, a.dtype), a[:, :1].shape)
+    return jnp.concatenate([col, a[:, :-1]], axis=1)
+
+
+def _pick_lane(a, k):
+    """a[p, k[p]] as (bt, 1): a masked lane reduction (k is (bt, 1) or a
+    scalar). Exactly one lane matches, so the max is that lane's value."""
+    lanes = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+    return jnp.max(jnp.where(lanes == k, a, _I32_MIN), axis=1,
+                   keepdims=True)
+
+
+def padded_seq_len(L: int) -> int:
+    """Lane length a sequence block is padded to: whole strips, plus two
+    spare strips so a strip cut at any in-range offset stays in bounds."""
+    return -(-L // STRIP) * STRIP + 2 * STRIP
+
+
+def pad_seq_lanes(x, L_pad: int):
+    """Pad the last (lane) axis of a sequence block to L_pad with base 4."""
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, L_pad - x.shape[-1])]
+    return jnp.pad(x, pad, constant_values=4)
+
+
+def _seq_strip(seq_ref, off):
+    """strip[p, c] = seq[p, off[p] + c] for c < STRIP: each pair's bases
+    from its own offset (off is (bt, 1), clipped into range).
+
+    Cut in two stages Mosaic lowers: a masked select over the row's
+    128-lane blocks picks blocks off//128 and off//128 + 1, then one
+    same-shape lane gather per block rotates them by off % 128.
+    """
+    bt, L = seq_ref.shape
+    nblk = L // STRIP
+    off = jnp.clip(off, 0, L - STRIP - 1)
+    blk = off // STRIP
+    zero = jnp.zeros((bt, STRIP), jnp.int32)
+
+    def pick(b, acc):
+        first, second = acc
+        x = seq_ref[:, pl.ds(pl.multiple_of(b * STRIP, STRIP), STRIP)]
+        return (jnp.where(blk == b, x, first),
+                jnp.where(blk + 1 == b, x, second))
+
+    first, second = jax.lax.fori_loop(0, nblk, pick, (zero, zero))
+    idx = jax.lax.broadcasted_iota(jnp.int32, (bt, STRIP), 1) + off % STRIP
+    rot = idx % STRIP
+    return jnp.where(idx < STRIP,
+                     jnp.take_along_axis(first, rot, axis=1),
+                     jnp.take_along_axis(second, rot, axis=1))
+
+
+def _move_windows(go_down, qw, rw, sq, sr):
+    """Advance the sequence windows with the band (the peripheral shifter).
+
+    qw[:, k] holds q[i_k - 1] and rw[:, k] holds r[j_k - 1] for band cell
+    k = (i_k, j_k). A down step keeps every lane's j and moves i by one,
+    so the query window shifts toward lane 0 and takes the next query
+    base from the strip; a right step keeps i, so the reference window
+    shifts away from lane 0 and takes the next reference base. Each strip
+    shifts once per base it hands out.
+    """
+    qw = jnp.where(go_down, _shift_toward_lane0(qw, sq[:, :1]), qw)
+    rw = jnp.where(go_down, rw, _shift_away_lane0(rw, sr[:, :1]))
+    sq = jnp.where(go_down, _shift_toward_lane0(sq, 0), sq)
+    sr = jnp.where(go_down, sr, _shift_toward_lane0(sr, 0))
+    return qw, rw, sq, sr
 
 
 # Column layout of the (bt, STATS_W) stats plane (the per-pair scalar
@@ -70,6 +163,7 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
                       q_ref, r_ref, n_ref, m_ref,          # inputs
                       tb_ref, lo_out_ref, stats_ref,        # outputs
                       u_s, v_s, x_s, y_s, H_s, lo_s, base_s,  # scratch
+                      qw_s, rw_s,  # sequence windows under the band
                       alive_s):  # SMEM all-retired chunk-skip flag
     o, e = sc.gap_open, sc.gap_extend
     oe = jnp.int32(o + e)
@@ -80,6 +174,7 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
     hdt = jnp.int16 if narrow else jnp.int32
     h_dead = DEAD16 if narrow else NEG
     tblk = pl.program_id(1)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (bt, B), 1)
 
     @pl.when(tblk == 0)
     def _init():
@@ -88,25 +183,23 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
         v_s[...] = z
         x_s[...] = z
         y_s[...] = z
-        H_s[...] = jnp.full((bt, B), h_dead, hdt).at[:, 0].set(0)
+        H_s[...] = jnp.where(lanes == 0, 0, h_dead).astype(hdt)
         lo_s[...] = jnp.zeros((bt, 1), jnp.int32)
         base_s[...] = jnp.zeros((bt, 1), jnp.int32)
+        # Diagonal 0 (lo = 0): lane k sits on row i = k, base q[k - 1].
+        qw_s[...] = _shift_away_lane0(q_ref[:, :B], 4)
+        rw_s[...] = jnp.full((bt, B), 4, jnp.int32)
         best0 = NEG if mode == "semiglobal" else 0
-        stats0 = (jnp.zeros((bt, STATS_W), jnp.int32)
-                  .at[:, _SCORE].set(NEG).at[:, _BEST].set(best0))
-        stats_ref[...] = stats0
+        cols = jax.lax.broadcasted_iota(jnp.int32, (bt, STATS_W), 1)
+        stats_ref[...] = jnp.where(cols == _SCORE, NEG,
+                                   jnp.where(cols == _BEST, best0, 0))
         alive_s[0] = 1
 
     n = n_ref[...].astype(jnp.int32)  # (bt, 1)
     m = m_ref[...].astype(jnp.int32)
-    q = q_ref[...].astype(jnp.int32)  # (bt, Lq)
-    r = r_ref[...].astype(jnp.int32)
-    Lq = q.shape[1]
-    Lr = r.shape[1]
-    lanes = jax.lax.broadcasted_iota(jnp.int32, (bt, B), 1)
 
     def step(s, carry):
-        u, v, x, y, H, lo, stats = carry
+        u, v, x, y, H, lo, stats, qw, rw, sq, sr = carry
         t = tblk * chunk + s + 1  # global wavefront step (diag index)
 
         # ---- direction (paper §IV-B2 + feasibility clamps) ----
@@ -116,10 +209,8 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
             heur_right = H[:, :1] > H[:, B - 1:]
         else:
             heur_right = (2 * lo + B) * (n + m) >= 2 * t * n
-        go_down = jnp.where(must_down, True,
-                            jnp.where(must_right, False, ~heur_right))
-        go_down_i = go_down.astype(jnp.int32)  # (bt,1)
-        lo_new = lo + go_down_i
+        go_down = must_down | (~must_right & ~heur_right)  # (bt,1)
+        lo_new = lo + go_down.astype(jnp.int32)
 
         # ---- neighbour alignment (the peripheral shifter) ----
         def pick_up(a, fill):
@@ -145,8 +236,9 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
         brow = valid & (i_vec == 0) & (j_vec >= 1)
         bcol = valid & (j_vec == 0) & (i_vec >= 1)
 
-        qb = jnp.take_along_axis(q, jnp.clip(i_vec - 1, 0, Lq - 1), axis=1)
-        rb = jnp.take_along_axis(r, jnp.clip(j_vec - 1, 0, Lr - 1), axis=1)
+        # Bases under the moved band; exact on interior cells, which are
+        # the only cells whose substitution score reaches an output.
+        qb, rb, sq, sr = _move_windows(go_down, qw, rw, sq, sr)
         is_match = (qb == rb) & (qb < 4) & (rb < 4)
         s_sub = jnp.where(is_match, jnp.int32(sc.match),
                           jnp.int32(-sc.mismatch))
@@ -173,15 +265,10 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
                                   jnp.where(a_new == x_arm, 1, 2))
             ext_e = ((x_arm + o) > a_new).astype(jnp.int32)
             ext_f = ((y_arm + o) > a_new).astype(jnp.int32)
-            code = (direction + 4 * ext_e + 8 * ext_f).astype(jnp.uint8)
-            code = jnp.where(interior, code, jnp.uint8(0))
+            code = direction + 4 * ext_e + 8 * ext_f
+            code = jnp.where(interior, code, 0)
             # Pack two lanes per byte in-register: only the packed
             # (bt, ceil(B/2)) rows ever reach the TBM store below.
-            # NOTE: validated bit-exact in interpret mode; the stride-2
-            # lane slices in pack_tb_lanes have not yet been lowered
-            # through Mosaic on a real TPU — if compile rejects them,
-            # fall back to packing just before the tb_ref store via a
-            # (bt, Bp, 2) reshape, or pad B to even.
             code = pack_tb_lanes(code)
         else:
             code = None
@@ -234,7 +321,7 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
                                   stats[:, _PBEST:_PBEST + 1])
 
         k_corner = jnp.clip(n - lo_new, 0, B - 1)  # (bt,1)
-        h_corner = jnp.take_along_axis(H_new, k_corner, axis=1)
+        h_corner = _pick_lane(H_new, k_corner)
         # done & active: a retired pair's frozen-carry recompute must not
         # leak into the capture (no-op when xdrop is None: done => active).
         score_new = jnp.where(done & active, h_corner,
@@ -255,9 +342,9 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
         best_prev = stats[:, _BEST:_BEST + 1]
         better = cand > best_prev
         best_new = jnp.where(better, cand, best_prev)
-        bi_new = jnp.where(better, jnp.take_along_axis(i_vec, k_best, axis=1),
+        bi_new = jnp.where(better, lo_new + k_best,
                            stats[:, _BEST_I:_BEST_I + 1])
-        bj_new = jnp.where(better, jnp.take_along_axis(j_vec, k_best, axis=1),
+        bj_new = jnp.where(better, t - lo_new - k_best,
                            stats[:, _BEST_J:_BEST_J + 1])
         stats_new = jnp.concatenate(
             [score_new, flo_new, best_new, bi_new, bj_new,
@@ -270,12 +357,14 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
         y = jnp.where(active, y_new, y)
         H = jnp.where(active, H_new, H)
         lo = jnp.where(active, lo_new, lo)
+        qw = jnp.where(active, qb, qw)
+        rw = jnp.where(active, rb, rw)
 
         # ---- stream traceback + band offsets out (TBM write) ----
         if collect_tb:
-            tb_ref[s] = code
-            lo_out_ref[s] = lo[:, 0]
-        return (u, v, x, y, H, lo, stats_new)
+            tb_ref[0, s] = code
+            lo_out_ref[0, s] = lo[:, 0]
+        return (u, v, x, y, H, lo, stats_new, qw, rw, sq, sr)
 
     def _sweep():
         # Widen the (possibly narrow) scratch carry to exact int32
@@ -283,14 +372,22 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
         # boundaries, and the base+relative reconstruction is exact, so
         # the loop values are bit-identical to the int32-scratch kernel.
         if narrow:
-            H0 = jnp.where(H_s[...] <= jnp.int16(DEAD16), jnp.int32(NEG),
-                           base_s[...] + H_s[...].astype(jnp.int32))
+            # Widen before comparing: the VPU has no int16 compare.
+            H_rel = H_s[...].astype(jnp.int32)
+            H0 = jnp.where(H_rel <= DEAD16, NEG, base_s[...] + H_rel)
         else:
             H0 = H_s[...]
+        lo0 = lo_s[...]
+        # This chunk's entering bases: the query from row lo0 + B - 1 on
+        # (down steps), the reference from column tblk * chunk - lo0 on
+        # (right steps).
+        sq = _seq_strip(q_ref, lo0 + (B - 1))
+        sr = _seq_strip(r_ref, tblk * chunk - lo0)
         carry = (u_s[...].astype(jnp.int32), v_s[...].astype(jnp.int32),
                  x_s[...].astype(jnp.int32), y_s[...].astype(jnp.int32),
-                 H0, lo_s[...], stats_ref[...])
-        u, v, x, y, H, lo, stats = jax.lax.fori_loop(0, chunk, step, carry)
+                 H0, lo0, stats_ref[...], qw_s[...], rw_s[...], sq, sr)
+        u, v, x, y, H, lo, stats, qw, rw, _, _ = jax.lax.fori_loop(
+            0, chunk, step, carry)
         if narrow:
             # Re-narrow for the chunk-boundary store: base = max live H
             # per pair; live cells keep H - base (in [-spread_bound, 0],
@@ -310,6 +407,8 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
         x_s[...] = x.astype(cdt)
         y_s[...] = y.astype(cdt)
         lo_s[...] = lo
+        qw_s[...] = qw
+        rw_s[...] = rw
         stats_ref[...] = stats
         if xdrop is not None:
             # All-retired/finished chunk skip: once every pair of this
@@ -330,7 +429,7 @@ def _wavefront_kernel(sc: ScoringConfig, band: int, chunk: int,
 def banded_align_pallas(q_pad, r_pad, n, m, *, sc: ScoringConfig, band: int,
                         adaptive: bool = True, collect_tb: bool = True,
                         mode: str = "global", batch_tile: int = 8,
-                        chunk: int = 128, interpret: bool = True,
+                        chunk: int = 128, interpret: bool | None = None,
                         t_max: int | None = None,
                         cell_dtype: str = "int32",
                         xdrop: int | None = None):
@@ -345,8 +444,10 @@ def banded_align_pallas(q_pad, r_pad, n, m, *, sc: ScoringConfig, band: int,
       collect_tb: stream traceback flags; False is the score-only fast
         path (no TBM traffic — the Fig. 14 "without traceback" mode).
       mode: "global" or "semiglobal" (free reference-end gaps).
-      chunk: wavefront steps per grid step (traceback block height).
-      interpret: run the kernel body in interpret mode (CPU validation).
+      chunk: wavefront steps per grid step (traceback block height), at
+        most STRIP.
+      interpret: run the kernel body in interpret mode (CPU validation);
+        None = `default_interpret()`.
       t_max: trimmed sweep length (must be >= max true n + m over the
         batch): the step-chunk grid shrinks to ceil(t_max / chunk)
         chunks, so a short-read batch in a long bucket stops sweeping
@@ -369,7 +470,10 @@ def banded_align_pallas(q_pad, r_pad, n, m, *, sc: ScoringConfig, band: int,
     bt = batch_tile
     if N % bt:
         raise ValueError(f"N={N} not divisible by batch_tile={bt}")
+    if chunk > STRIP:
+        raise ValueError(f"chunk={chunk} exceeds the strip width {STRIP}")
     nb = N // bt
+    Lq_pad, Lr_pad = padded_seq_len(Lq), padded_seq_len(Lr)
     T = int(t_max) if t_max is not None else Lq + Lr
     T_pad = int(-(-T // chunk) * chunk)
     n_chunks = T_pad // chunk
@@ -397,8 +501,8 @@ def banded_align_pallas(q_pad, r_pad, n, m, *, sc: ScoringConfig, band: int,
         out_shapes = (stats_shape,)
         out_specs = (stats_spec,)
     in_specs = [
-        pl.BlockSpec((1, bt, Lq), lambda b, t: (b, 0, 0)),
-        pl.BlockSpec((1, bt, Lr), lambda b, t: (b, 0, 0)),
+        pl.BlockSpec((1, bt, Lq_pad), lambda b, t: (b, 0, 0)),
+        pl.BlockSpec((1, bt, Lr_pad), lambda b, t: (b, 0, 0)),
         pl.BlockSpec((1, bt, 1), lambda b, t: (b, 0, 0)),
         pl.BlockSpec((1, bt, 1), lambda b, t: (b, 0, 0)),
     ]
@@ -412,17 +516,21 @@ def banded_align_pallas(q_pad, r_pad, n, m, *, sc: ScoringConfig, band: int,
         pltpu.VMEM((bt, band), hdt),        # H (base-relative if narrow)
         pltpu.VMEM((bt, 1), jnp.int32),     # lo
         pltpu.VMEM((bt, 1), jnp.int32),     # base (narrow H offset)
+        pltpu.VMEM((bt, band), jnp.int32),  # query window
+        pltpu.VMEM((bt, band), jnp.int32),  # reference window
         pltpu.SMEM((1,), jnp.int32),        # alive (xdrop chunk skip)
     ]
 
     def unsqueeze_kernel(q_r, r_r, n_r, m_r, *rest):
-        # Blocks carry a leading size-1 grid dim; present 2-D views to the
-        # kernel body. Without collect_tb there are no tb/lo outputs.
+        # Blocks carry a leading size-1 grid dim; present 2-D views of the
+        # inputs and stats to the kernel body. The tb/lo outputs keep it
+        # (Mosaic refuses a dynamic row store through a sliced view).
+        # Without collect_tb there are no tb/lo outputs.
         if collect_tb:
             tb_r, lo_r, st_r = rest[:3]
             scratch = rest[3:]
             kernel(q_r.at[0], r_r.at[0], n_r.at[0], m_r.at[0],
-                   tb_r.at[0], lo_r.at[0], st_r.at[0], *scratch)
+                   tb_r, lo_r, st_r.at[0], *scratch)
         else:
             st_r = rest[0]
             scratch = rest[1:]
@@ -436,9 +544,9 @@ def banded_align_pallas(q_pad, r_pad, n, m, *, sc: ScoringConfig, band: int,
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=scratch_shapes,
-        interpret=interpret,
-    )(q_pad.reshape(nb, bt, Lq).astype(jnp.int32),
-      r_pad.reshape(nb, bt, Lr).astype(jnp.int32),
+        interpret=resolve_interpret(interpret),
+    )(pad_seq_lanes(q_pad.reshape(nb, bt, Lq).astype(jnp.int32), Lq_pad),
+      pad_seq_lanes(r_pad.reshape(nb, bt, Lr).astype(jnp.int32), Lr_pad),
       n.reshape(nb, bt, 1).astype(jnp.int32),
       m.reshape(nb, bt, 1).astype(jnp.int32))
 

@@ -18,7 +18,7 @@ def banded_align_kernel_batch(q_pad, r_pad, n, m, *, sc: ScoringConfig,
                               band: int, adaptive: bool = True,
                               collect_tb: bool = True, mode: str = "global",
                               batch_tile: int = 8, chunk: int = 128,
-                              interpret: bool = True,
+                              interpret: bool | None = None,
                               t_max: int | None = None,
                               cell_dtype: str = "int32",
                               xdrop: int | None = None):

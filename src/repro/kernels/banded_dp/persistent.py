@@ -44,11 +44,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.banded import DEAD16, pack_tb_lanes, packed_tb_width
 from repro.core.scoring import ScoringConfig
-from repro.kernels.banded_dp.banded_dp import (DEAD, NEG, STATS_W, _BEST,
-                                               _BEST_I, _BEST_J, _FINAL_LO,
-                                               _PBEST, _SCORE, _STATUS,
+from repro.kernels.banded_dp.banded_dp import (DEAD, NEG, STATS_W, STRIP,
+                                               _BEST, _BEST_I, _BEST_J,
+                                               _FINAL_LO, _PBEST, _SCORE,
+                                               _STATUS, _move_windows,
+                                               _pick_lane, _seq_strip,
                                                _shift_away_lane0,
-                                               _shift_toward_lane0)
+                                               _shift_toward_lane0,
+                                               pad_seq_lanes, padded_seq_len,
+                                               resolve_interpret)
 
 
 def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
@@ -60,6 +64,7 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
                        q_ref, r_ref, n_ref, m_ref,
                        tb_ref, lo_out_ref, stats_ref,
                        u_s, v_s, x_s, y_s, H_s, lo_s, base_s,
+                       qw_s, rw_s,  # sequence windows under the band
                        alive_s):  # SMEM all-retired chunk-skip flag
     o, e = sc.gap_open, sc.gap_extend
     oe = jnp.int32(o + e)
@@ -71,6 +76,9 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
     g = pl.program_id(0)
     cblk = pl.program_id(2)
     band_g = band_ref[g]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (bt, B), 1)
+    q_blk = q_ref.at[0, 0]  # (bt, Lq_pad)
+    r_blk = r_ref.at[0, 0]
 
     live = (pl.program_id(1) < ntiles_ref[g]) & (cblk < chunks_ref[g])
     if xdrop is not None:
@@ -87,40 +95,35 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
             v_s[...] = z
             x_s[...] = z
             y_s[...] = z
-            H_s[...] = jnp.full((bt, B), DEAD16 if narrow else NEG,
-                                hdt).at[:, 0].set(0)
+            H_s[...] = jnp.where(lanes == 0, 0,
+                                 DEAD16 if narrow else NEG).astype(hdt)
             lo_s[...] = jnp.zeros((bt, 1), jnp.int32)
             base_s[...] = jnp.zeros((bt, 1), jnp.int32)
+            # Diagonal 0 (lo = 0): lane k sits on row i = k, base q[k - 1].
+            qw_s[...] = _shift_away_lane0(q_blk[:, :B], 4)
+            rw_s[...] = jnp.full((bt, B), 4, jnp.int32)
             best0 = NEG if mode == "semiglobal" else 0
-            stats_ref[...] = (
-                jnp.zeros((1, 1, bt, STATS_W), jnp.int32)
-                .at[..., _SCORE].set(NEG).at[..., _BEST].set(best0))
+            cols = jax.lax.broadcasted_iota(jnp.int32, (bt, STATS_W), 1)
+            stats_ref[0, 0] = jnp.where(cols == _SCORE, NEG,
+                                        jnp.where(cols == _BEST, best0, 0))
             alive_s[0] = 1
 
         n = n_ref[0, 0].astype(jnp.int32)  # (bt, 1)
         m = m_ref[0, 0].astype(jnp.int32)
-        q = q_ref[0, 0].astype(jnp.int32)  # (bt, Lq_max)
-        r = r_ref[0, 0].astype(jnp.int32)
-        Lq = q.shape[1]
-        Lr = r.shape[1]
-        lanes = jax.lax.broadcasted_iota(jnp.int32, (bt, B), 1)
         in_lane = lanes < band_g        # dynamic-band lane mask
 
         def step(s, carry):
-            u, v, x, y, H, lo, stats = carry
+            u, v, x, y, H, lo, stats, qw, rw, sq, sr = carry
             t = cblk * chunk + s + 1
 
             # ---- direction (dynamic band width band_g) ----
             must_down = (lo + (n + m - t)) < (n - band_g + 1)
             must_right = lo >= n
             if adaptive:
-                h_last = jnp.take_along_axis(
-                    H, jnp.full((bt, 1), band_g - 1, jnp.int32), axis=1)
-                heur_right = H[:, :1] > h_last
+                heur_right = H[:, :1] > _pick_lane(H, band_g - 1)
             else:
                 heur_right = (2 * lo + band_g) * (n + m) >= 2 * t * n
-            go_down = jnp.where(must_down, True,
-                                jnp.where(must_right, False, ~heur_right))
+            go_down = must_down | (~must_right & ~heur_right)
             lo_new = lo + go_down.astype(jnp.int32)
 
             def pick_up(a, fill):
@@ -147,10 +150,9 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
             brow = valid & (i_vec == 0) & (j_vec >= 1)
             bcol = valid & (j_vec == 0) & (i_vec >= 1)
 
-            qb = jnp.take_along_axis(q, jnp.clip(i_vec - 1, 0, Lq - 1),
-                                     axis=1)
-            rb = jnp.take_along_axis(r, jnp.clip(j_vec - 1, 0, Lr - 1),
-                                     axis=1)
+            # The windows span all B_max lanes (dead lanes hold real
+            # bases too), so they move exactly as in the per-group kernel.
+            qb, rb, sq, sr = _move_windows(go_down, qw, rw, sq, sr)
             is_match = (qb == rb) & (qb < 4) & (rb < 4)
             s_sub = jnp.where(is_match, jnp.int32(sc.match),
                               jnp.int32(-sc.mismatch))
@@ -178,9 +180,8 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
                                       jnp.where(a_new == x_arm, 1, 2))
                 ext_e = ((x_arm + o) > a_new).astype(jnp.int32)
                 ext_f = ((y_arm + o) > a_new).astype(jnp.int32)
-                code = (direction + 4 * ext_e + 8 * ext_f).astype(jnp.uint8)
-                code = jnp.where(interior, code, jnp.uint8(0))
-                code = pack_tb_lanes(code)
+                code = direction + 4 * ext_e + 8 * ext_f
+                code = pack_tb_lanes(jnp.where(interior, code, 0))
             else:
                 code = None
 
@@ -230,7 +231,7 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
                                       stats[:, _PBEST:_PBEST + 1])
 
             k_corner = jnp.clip(n - lo_new, 0, band_g - 1)
-            h_corner = jnp.take_along_axis(H_new, k_corner, axis=1)
+            h_corner = _pick_lane(H_new, k_corner)
             score_new = jnp.where(done & active, h_corner,
                                   stats[:, _SCORE:_SCORE + 1])
             flo_new = jnp.where(done & active, lo_new,
@@ -248,11 +249,9 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
             best_prev = stats[:, _BEST:_BEST + 1]
             better = cand > best_prev
             best_new = jnp.where(better, cand, best_prev)
-            bi_new = jnp.where(better,
-                               jnp.take_along_axis(i_vec, k_best, axis=1),
+            bi_new = jnp.where(better, lo_new + k_best,
                                stats[:, _BEST_I:_BEST_I + 1])
-            bj_new = jnp.where(better,
-                               jnp.take_along_axis(j_vec, k_best, axis=1),
+            bj_new = jnp.where(better, t - lo_new - k_best,
                                stats[:, _BEST_J:_BEST_J + 1])
             stats_new = jnp.concatenate(
                 [score_new, flo_new, best_new, bi_new, bj_new,
@@ -265,21 +264,29 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
             y = jnp.where(active, y_new, y)
             H = jnp.where(active, H_new, H)
             lo = jnp.where(active, lo_new, lo)
+            qw = jnp.where(active, qb, qw)
+            rw = jnp.where(active, rb, rw)
 
             if collect_tb:
                 tb_ref[0, 0, s] = code
                 lo_out_ref[0, 0, s] = lo[:, 0]
-            return (u, v, x, y, H, lo, stats_new)
+            return (u, v, x, y, H, lo, stats_new, qw, rw, sq, sr)
 
         if narrow:
-            H0 = jnp.where(H_s[...] <= jnp.int16(DEAD16), jnp.int32(NEG),
-                           base_s[...] + H_s[...].astype(jnp.int32))
+            # Widen before comparing: the VPU has no int16 compare.
+            H_rel = H_s[...].astype(jnp.int32)
+            H0 = jnp.where(H_rel <= DEAD16, NEG, base_s[...] + H_rel)
         else:
             H0 = H_s[...]
+        lo0 = lo_s[...]
+        # Entering bases of this chunk (see the per-group kernel).
+        sq = _seq_strip(q_blk, lo0 + (B - 1))
+        sr = _seq_strip(r_blk, cblk * chunk - lo0)
         carry = (u_s[...].astype(jnp.int32), v_s[...].astype(jnp.int32),
                  x_s[...].astype(jnp.int32), y_s[...].astype(jnp.int32),
-                 H0, lo_s[...], stats_ref[0, 0])
-        u, v, x, y, H, lo, stats = jax.lax.fori_loop(0, chunk, step, carry)
+                 H0, lo0, stats_ref[0, 0], qw_s[...], rw_s[...], sq, sr)
+        u, v, x, y, H, lo, stats, qw, rw, _, _ = jax.lax.fori_loop(
+            0, chunk, step, carry)
         if narrow:
             live = H > DEAD
             base = jnp.max(jnp.where(live, H, NEG), axis=1, keepdims=True)
@@ -294,6 +301,8 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
         x_s[...] = x.astype(cdt)
         y_s[...] = y.astype(cdt)
         lo_s[...] = lo
+        qw_s[...] = qw
+        rw_s[...] = rw
         stats_ref[0, 0] = stats
         if xdrop is not None:
             # Drop the flag once every pair of this (group, tile) is
@@ -307,7 +316,8 @@ def _persistent_kernel(sc: ScoringConfig, B_max: int, chunk: int,
 def persistent_align_pallas(q_st, r_st, n_st, m_st, band_arr, chunks_arr,
                             ntiles_arr, *, sc: ScoringConfig, geom: tuple,
                             bt: int, chunk: int, adaptive: bool,
-                            collect_tb: bool, mode: str, interpret: bool,
+                            collect_tb: bool, mode: str,
+                            interpret: bool | None = None,
                             cell_dtype: str = "int32",
                             xdrop: int | None = None):
     """Run the persistent megakernel over a stacked multi-group request.
@@ -330,8 +340,11 @@ def persistent_align_pallas(q_st, r_st, n_st, m_st, band_arr, chunks_arr,
     length but Bp_max wide — `pack_tb_lanes` is positional, so decoding
     with the group's own band width reads identical nibbles).
     """
+    if chunk > STRIP:
+        raise ValueError(f"chunk={chunk} exceeds the strip width {STRIP}")
     G, nb_max = q_st.shape[:2]
-    Lq, Lr = q_st.shape[3], r_st.shape[3]
+    Lq = padded_seq_len(q_st.shape[3])
+    Lr = padded_seq_len(r_st.shape[3])
     B_max = max(gm[2] for gm in geom)
     n_chunks_max = int(max(chunks_arr))
     T_pad_max = n_chunks_max * chunk
@@ -377,6 +390,8 @@ def persistent_align_pallas(q_st, r_st, n_st, m_st, band_arr, chunks_arr,
         pltpu.VMEM((bt, B_max), hdt),       # H (base-relative if narrow)
         pltpu.VMEM((bt, 1), jnp.int32),     # lo
         pltpu.VMEM((bt, 1), jnp.int32),     # base
+        pltpu.VMEM((bt, B_max), jnp.int32), # query window
+        pltpu.VMEM((bt, B_max), jnp.int32), # reference window
         pltpu.SMEM((1,), jnp.int32),        # alive (xdrop chunk skip)
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -403,10 +418,11 @@ def persistent_align_pallas(q_st, r_st, n_st, m_st, band_arr, chunks_arr,
         dispatch_kernel,
         grid_spec=grid_spec,
         out_shape=out_shapes,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(band_arr, jnp.int32), jnp.asarray(chunks_arr, jnp.int32),
       jnp.asarray(ntiles_arr, jnp.int32),
-      jnp.asarray(q_st), jnp.asarray(r_st),
+      pad_seq_lanes(jnp.asarray(q_st, jnp.int32), Lq),
+      pad_seq_lanes(jnp.asarray(r_st, jnp.int32), Lr),
       jnp.asarray(n_st, jnp.int32), jnp.asarray(m_st, jnp.int32))
 
     stats = outs[-1]

@@ -22,7 +22,7 @@ import argparse
 import time
 
 from repro.configs.rapidx import CONFIG as RAPIDX
-from repro.core.engine import AlignmentEngine
+from repro.core.engine import AlignmentEngine, enable_compilation_cache
 from repro.data.genome import ReadSimulator, random_genome
 from repro.map import (MinimizerIndex, ReadMapper, STATUS_MAPPED,
                        STATUS_SEED_CAPPED)
@@ -72,6 +72,7 @@ def main():
     if args.replicas < 1:
         ap.error("--replicas must be >= 1")
 
+    enable_compilation_cache()
     genome = random_genome(args.genome, seed=args.seed)
     t0 = time.perf_counter()
     index = MinimizerIndex(genome, k=args.k, w=args.w,

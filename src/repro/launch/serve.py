@@ -16,8 +16,13 @@ replicas (DESIGN.md §11) — scale-OUT by dispatcher count, where the
 mesh is scale-UP by device count, so the replicated path runs each
 replica mesh-free.
 
+Compiled programs persist in JAX's compilation cache
+(`core.engine.enable_compilation_cache`: JAX_COMPILATION_CACHE_DIR when
+set, else one fixed directory in the checkout), so a restarted replica
+deserialises its dispatch programs instead of recompiling them.
+
     PYTHONPATH=src python -m repro.launch.serve --reads 512 --rate 2000 \
-        --policy adaptive --warmup --compilation-cache-dir /tmp/rapidx-cc
+        --policy adaptive --warmup
 
     PYTHONPATH=src python -m repro.launch.serve --reads 512 --replicas 2
 """
@@ -30,7 +35,7 @@ import time
 import jax
 
 from repro.configs.rapidx import CONFIG as RAPIDX
-from repro.core.engine import AlignmentEngine
+from repro.core.engine import AlignmentEngine, enable_compilation_cache
 from repro.data.genome import ReadSimulator, random_genome
 from repro.launch.mesh import make_debug_mesh
 from repro.serve import AlignmentRouter, AlignmentService
@@ -66,10 +71,6 @@ def main():
     ap.add_argument("--warmup", action="store_true",
                     help="pre-compile the stream's dispatch signatures "
                          "before accepting traffic")
-    ap.add_argument("--compilation-cache-dir", default=None,
-                    help="persistent XLA compilation cache directory: a "
-                         "restarted replica deserialises its dispatch "
-                         "programs instead of recompiling them")
     ap.add_argument("--xdrop", type=int, default=None,
                     help="X-drop early-termination threshold: retire a "
                          "pair once its band max falls this far below "
@@ -89,6 +90,7 @@ def main():
     if args.replicas < 1:
         ap.error("--replicas must be >= 1")
 
+    enable_compilation_cache()
     n_dev = len(jax.devices())
     use_mesh = (not args.no_mesh and args.dispatch != "persistent"
                 and args.replicas == 1)
@@ -97,8 +99,7 @@ def main():
     def make_engine(_i=0):
         return AlignmentEngine(
             backend="auto", sc=RAPIDX.scoring, capacity=args.capacity,
-            mesh=mesh, dispatch=args.dispatch, xdrop=args.xdrop,
-            compilation_cache_dir=args.compilation_cache_dir)
+            mesh=mesh, dispatch=args.dispatch, xdrop=args.xdrop)
 
     engine = make_engine()
     print(f"[serve] devices={n_dev} backend={engine.backend_name} "
