@@ -1,0 +1,11 @@
+"""Device milliseconds of the traceback walker per completed read, from
+the trace (summed over devices)."""
+
+import trace_reduce
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    s = trace_reduce.named_seconds(run.trace, "walker")
+    return s * 1e3 / max(run.record["reads"], 1)
