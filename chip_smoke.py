@@ -29,11 +29,12 @@ same engine on one device, compares them bit for bit, and checks that
 the outputs span four devices and that the compiled program has no
 collectives.
 
-Every phase prints its wall seconds and the seconds its XLA and Mosaic
-backend compiles took (compiles served by the persistent cache do not
-count). They are smoke timings of one run, not benchmarks. A failed
-phase, or a host without a TPU, exits non-zero with no result line. On
-success the last line of stdout is
+Every phase prints its wall seconds and the programs it built, by
+function, with their backend seconds (`repro.obs.programs_built`; a
+program loaded from the persistent cache counts as built). They are
+smoke timings of one run, not benchmarks. A failed phase, or a host
+without a TPU, exits non-zero with no result line. On success the last
+line of stdout is
 `{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`.
 """
 
@@ -59,14 +60,6 @@ MAPPING_FLOORS = {"illumina": 0.99, "pacbio": 0.95}
 #: Result keys compared bit for bit between two engine runs.
 COMPARED = ("score", "best_score", "best_i", "best_j", "final_lo", "status",
             "band")
-_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-_compile_secs: list[float] = []
-
-
-def _on_event(event: str, duration: float, **_) -> None:
-    if event == _BACKEND_COMPILE:
-        _compile_secs.append(duration)
-
 
 def log(msg: str) -> None:
     print(f"[chip_smoke] {msg}", flush=True)
@@ -80,15 +73,21 @@ def check(ok, what) -> None:
 
 def run_phase(name: str, fn, *args):
     """Run one phase; print its smoke timings; re-raise its failure."""
-    n_before = len(_compile_secs)
+    from repro import obs
+
+    before = obs.programs_built()
     t0 = time.perf_counter()
     try:
         return fn(*args)
     finally:
         wall = time.perf_counter() - t0
-        compile_s = sum(_compile_secs[n_before:])
-        log(f"phase {name}: wall {wall:.3f}s, backend compile "
-            f"{compile_s:.3f}s (smoke timing, not a benchmark)")
+        built = {}
+        for fun, now in obs.programs_built().items():
+            was = before.get(fun, {"count": 0, "seconds": 0.0})
+            built[fun] = {"count": now["count"] - was["count"],
+                          "seconds": now["seconds"] - was["seconds"]}
+        log(f"phase {name}: wall {wall:.3f}s, programs built: "
+            f"{obs.describe(built)} (smoke timing, not a benchmark)")
 
 
 def simulate_pairs(profile: str, read_len: int, count: int, seed: int,
@@ -302,10 +301,11 @@ def main(argv=None) -> int:
         print(f"chip_smoke: needs a TPU; JAX found {dev.platform}",
               file=sys.stderr)
         return 2
+    from repro import obs
     from repro.core.engine import enable_compilation_cache
 
     log(f"compile cache: {enable_compilation_cache()}")
-    jax.monitoring.register_event_duration_secs_listener(_on_event)
+    obs.install()
     try:
         run_phase("device", phase_device)
         if args.four_chips:

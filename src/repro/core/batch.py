@@ -28,6 +28,7 @@ import functools
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import banded
 from repro.core.backends import get_backend
 from repro.core.scoring import ScoringConfig, MINIMAP2, adaptive_bandwidth
@@ -266,32 +267,37 @@ def finalize_dispatch(outs, n, m, *, band: int, num_real: int,
     if collect_tb and decode == "device":
         from repro.core.traceback_device import rle_to_cigars
 
-        # Trim the fetch across slices: cig_len is a tiny (k,) fetch and
-        # bounds the device-side column slice of the op/run planes.
-        lens = [fetch(o["cig_len"]) for o in outs]
-        k_used = max(1, *(int(l.max(initial=0)) for l in lens))
-        merged = {}
-        for key in outs[0]:
-            if key in ("cig_ops", "cig_runs"):
-                merged[key] = np.concatenate(
-                    [fetch(o[key][:, :k_used]) for o in outs]
-                )[:num_real]
-            elif key == "cig_len":
-                merged[key] = np.concatenate(lens)[:num_real]
-            else:
-                merged[key] = np.concatenate(
-                    [fetch(o[key]) for o in outs])[:num_real]
-        merged["cigars"] = rle_to_cigars(merged["cig_ops"],
-                                         merged["cig_runs"],
-                                         merged["cig_len"])
-        _none_rejected_cigars(merged)
+        with obs.span("serve.fetch") as sp:
+            # Trim the fetch across slices: cig_len is a tiny (k,) fetch and
+            # bounds the device-side column slice of the op/run planes.
+            lens = [fetch(o["cig_len"]) for o in outs]
+            k_used = max(1, *(int(l.max(initial=0)) for l in lens))
+            merged = {}
+            for key in outs[0]:
+                if key in ("cig_ops", "cig_runs"):
+                    merged[key] = np.concatenate(
+                        [fetch(o[key][:, :k_used]) for o in outs]
+                    )[:num_real]
+                elif key == "cig_len":
+                    merged[key] = np.concatenate(lens)[:num_real]
+                else:
+                    merged[key] = np.concatenate(
+                        [fetch(o[key]) for o in outs])[:num_real]
+            sp.set_metadata(bytes=fetched)
+        with obs.span("serve.decode", pairs=num_real):
+            merged["cigars"] = rle_to_cigars(merged["cig_ops"],
+                                             merged["cig_runs"],
+                                             merged["cig_len"])
+            _none_rejected_cigars(merged)
         if stats is not None:
             stats["fetched_bytes"] = fetched
         return merged
     merged = {}
-    for key in outs[0]:
-        merged[key] = np.concatenate(
-            [fetch(o[key]) for o in outs])[:num_real]
+    with obs.span("serve.fetch") as sp:
+        for key in outs[0]:
+            merged[key] = np.concatenate(
+                [fetch(o[key]) for o in outs])[:num_real]
+        sp.set_metadata(bytes=fetched)
     if collect_tb:
         if mode == "semiglobal":
             starts = np.stack([merged["best_i"], merged["best_j"]], axis=1)
@@ -305,10 +311,11 @@ def finalize_dispatch(outs, n, m, *, band: int, num_real: int,
         rejected = merged.get("status")
         if rejected is not None:
             starts = np.where((rejected != 0)[:, None], 0, starts)
-        merged["cigars"] = banded.traceback_banded_batch(
-            merged["tb"], merged["los"], n[:num_real], m[:num_real],
-            band, starts=starts)
-        _none_rejected_cigars(merged)
+        with obs.span("serve.decode", pairs=num_real):
+            merged["cigars"] = banded.traceback_banded_batch(
+                merged["tb"], merged["los"], n[:num_real], m[:num_real],
+                band, starts=starts)
+            _none_rejected_cigars(merged)
     if stats is not None:
         stats["fetched_bytes"] = fetched
     return merged

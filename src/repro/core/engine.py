@@ -49,6 +49,7 @@ import pathlib
 
 import numpy as np
 
+from repro import obs
 from repro.core.backends import available_backends, get_backend, \
     resolve_backend
 from repro.core.banded import validate_narrow_cells
@@ -281,6 +282,7 @@ class AlignmentEngine:
     compilation_cache_dir: str | None = None
 
     def __post_init__(self):
+        obs.install()
         if self.compilation_cache_dir is not None:
             enable_compilation_cache(self.compilation_cache_dir)
         self.backend = get_backend(self.backend,
@@ -511,29 +513,32 @@ class AlignmentEngine:
         out = {k: np.zeros(N, np.int32) for k in SCALAR_KEYS}
         out["band"] = np.zeros(N, np.int32)
         merged = pending.outs
-        if pending.collect_tb:
-            from repro.core.traceback_device import rle_to_cigars
-            lens = fetch(merged["cig_len"])
-            k_used = max(int(lens.max(initial=0)), 1)
-            ops = fetch(merged["cig_ops"][:, :k_used])
-            runs = fetch(merged["cig_runs"][:, :k_used])
-        scalars = {k: fetch(merged[k]) for k in SCALAR_KEYS}
+        with obs.span("serve.fetch") as sp:
+            if pending.collect_tb:
+                from repro.core.traceback_device import rle_to_cigars
+                lens = fetch(merged["cig_len"])
+                k_used = max(int(lens.max(initial=0)), 1)
+                ops = fetch(merged["cig_ops"][:, :k_used])
+                runs = fetch(merged["cig_runs"][:, :k_used])
+            scalars = {k: fetch(merged[k]) for k in SCALAR_KEYS}
+            sp.set_metadata(bytes=fetched)
         cigars: list = [None] * N
         off = 0
-        for g, grp in zip(pending.groups, pending.batch):
-            idx = g.indices
-            n_real = len(idx)
-            for key in SCALAR_KEYS:
-                out[key][idx] = scalars[key][off:off + n_real]
-            out["band"][idx] = g.spec.band
-            if pending.collect_tb:
-                cigs = rle_to_cigars(ops[off:off + n_real],
-                                     runs[off:off + n_real],
-                                     lens[off:off + n_real])
-                st = scalars["status"][off:off + n_real]
-                for pos, cig, rej in zip(idx, cigs, st != 0):
-                    cigars[pos] = None if rej else cig
-            off += grp[0].shape[0]  # advance past this group's padded rows
+        with obs.span("serve.decode", pairs=N):
+            for g, grp in zip(pending.groups, pending.batch):
+                idx = g.indices
+                n_real = len(idx)
+                for key in SCALAR_KEYS:
+                    out[key][idx] = scalars[key][off:off + n_real]
+                out["band"][idx] = g.spec.band
+                if pending.collect_tb:
+                    cigs = rle_to_cigars(ops[off:off + n_real],
+                                         runs[off:off + n_real],
+                                         lens[off:off + n_real])
+                    st = scalars["status"][off:off + n_real]
+                    for pos, cig, rej in zip(idx, cigs, st != 0):
+                        cigars[pos] = None if rej else cig
+                off += grp[0].shape[0]  # past this group's padded rows
         if pending.collect_tb:
             out["cigars"] = cigars
         if stats is not None:

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.banded import _OP_CHARS, _OP_D, _OP_I, _OP_M, \
     select_tb_nibble
 
@@ -181,10 +182,12 @@ def fetch_rle(out: dict) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     them — so host traffic per pair is ``5 * K_used + 4`` bytes, O(path
     segments), never the static K = t_max bound.
     """
-    lens = np.asarray(out["cig_len"])
-    k_used = max(int(lens.max(initial=0)), 1)
-    ops = np.asarray(out["cig_ops"][:, :k_used])
-    runs = np.asarray(out["cig_runs"][:, :k_used])
+    with obs.span("serve.fetch") as sp:
+        lens = np.asarray(out["cig_len"])
+        k_used = max(int(lens.max(initial=0)), 1)
+        ops = np.asarray(out["cig_ops"][:, :k_used])
+        runs = np.asarray(out["cig_runs"][:, :k_used])
+        sp.set_metadata(bytes=lens.nbytes + ops.nbytes + runs.nbytes)
     return ops, runs, lens
 
 

@@ -21,6 +21,7 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro import obs
 from repro.configs.rapidx import CONFIG as RAPIDX
 from repro.core.engine import AlignmentEngine, enable_compilation_cache
 from repro.data.genome import ReadSimulator, random_genome
@@ -124,6 +125,7 @@ def main():
           f"p50={stats['p50_ms']:.1f}ms p99={stats['p99_ms']:.1f}ms "
           f"fill_ratio={stats['fill_ratio']:.2f} "
           f"dispatches={stats['dispatches']}")
+    print(f"[map] programs built: {obs.describe(obs.programs_built())}")
 
 
 if __name__ == "__main__":

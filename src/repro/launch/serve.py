@@ -34,6 +34,7 @@ import time
 
 import jax
 
+from repro import obs
 from repro.configs.rapidx import CONFIG as RAPIDX
 from repro.core.engine import AlignmentEngine, enable_compilation_cache
 from repro.data.genome import ReadSimulator, random_genome
@@ -164,6 +165,7 @@ def main():
           f"rejected={stats['rejected']}{tier} "
           f"flushes=fill:{stats['flush_fill']}/timeout:"
           f"{stats['flush_timeout']}/stall:{stats['flush_stall']}")
+    print(f"[serve] programs built: {obs.describe(obs.programs_built())}")
 
 
 if __name__ == "__main__":

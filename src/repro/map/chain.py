@@ -156,7 +156,11 @@ def _chain_batch_fn(k: int, max_gap: int, max_dd: int):
 
     one = functools.partial(_chain_one, k=k, max_gap=max_gap,
                             max_dd=max_dd)
-    return jax.jit(jax.vmap(one))
+
+    def chain_anchors(qp, rp, valid):
+        return jax.vmap(one)(qp, rp, valid)
+
+    return jax.jit(chain_anchors)
 
 
 def _pad_anchors(anchor_sets, cap: int):

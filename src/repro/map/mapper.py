@@ -40,6 +40,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import obs
 from repro.data.genome import reverse_complement
 from repro.map.chain import Chain, ChainParams, chain_batch, top_chains
 from repro.map.index import MinimizerIndex
@@ -95,6 +96,15 @@ def _mapq(s1: int, s2: int, n_candidates: int) -> int:
         return 60
     margin = max(s1 - max(s2, 0), 0)
     return min(60, (60 * margin) // max(s1, 1))
+
+
+def _await(future):
+    """A submitted alignment's result. A wait for one not yet resolved
+    is a wait span: the client is blocked on the service."""
+    if future.done():
+        return future.result()
+    with obs.span("map.await", wait=1):
+        return future.result()
 
 
 class ReadMapper:
@@ -155,13 +165,17 @@ class ReadMapper:
         (lookups, per-read capped/total counters); lookups is a flat
         list of LookupResults, strand-major per read."""
         lookups, flags = [], []
-        for read in reads:
-            probes = [self.index.lookup(read)]
-            if self.both_strands:
-                probes.append(self.index.lookup(reverse_complement(read)))
-            lookups.append(probes)
-            flags.append((sum(p.capped for p in probes),
-                          sum(p.total for p in probes)))
+        with obs.span("map.seed", reads=len(reads)) as sp:
+            for read in reads:
+                probes = [self.index.lookup(read)]
+                if self.both_strands:
+                    probes.append(self.index.lookup(reverse_complement(read)))
+                lookups.append(probes)
+                flags.append((sum(p.capped for p in probes),
+                              sum(p.total for p in probes)))
+            if obs.enabled():
+                sp.set_metadata(anchors=sum(len(p.q_pos) for probes in lookups
+                                            for p in probes))
         return lookups, flags
 
     def _chain(self, lookups):
@@ -169,23 +183,24 @@ class ReadMapper:
         list, then per-read top-chain extraction. Returns per-read
         candidate lists sorted best-first under a total order."""
         flat = [(p.q_pos, p.r_pos) for probes in lookups for p in probes]
-        chained = chain_batch(flat, self.params)
-        out, pos = [], 0
-        for probes in lookups:
-            cands = []
-            for strand, probe in enumerate(probes):
-                for chain in top_chains(
-                        probe.q_pos, probe.r_pos, chained[pos],
-                        max_chains=self.max_candidates,
-                        min_sep=self.min_sep,
-                        cap=self.params.anchors_cap):
-                    cands.append(_Candidate(chain=chain, strand=strand))
-                pos += 1
-            # Total order: score desc, then strand, then locus — the
-            # ranking (and therefore every MapResult) is reproducible.
-            cands.sort(key=lambda c: (-c.chain.score, c.strand,
-                                      c.chain.diag_start))
-            out.append(cands[:self.max_candidates])
+        with obs.span("map.chain", sets=len(flat)):
+            chained = chain_batch(flat, self.params)
+            out, pos = [], 0
+            for probes in lookups:
+                cands = []
+                for strand, probe in enumerate(probes):
+                    for chain in top_chains(
+                            probe.q_pos, probe.r_pos, chained[pos],
+                            max_chains=self.max_candidates,
+                            min_sep=self.min_sep,
+                            cap=self.params.anchors_cap):
+                        cands.append(_Candidate(chain=chain, strand=strand))
+                    pos += 1
+                # Total order: score desc, then strand, then locus — the
+                # ranking (and therefore every MapResult) is reproducible.
+                cands.sort(key=lambda c: (-c.chain.score, c.strand,
+                                          c.chain.diag_start))
+                out.append(cands[:self.max_candidates])
         return out
 
     def _submit(self, read, cand: _Candidate, rank: int):
@@ -213,48 +228,53 @@ class ReadMapper:
         order. All candidates of all reads are submitted before any
         result is awaited, so the service micro-batches across the whole
         batch (that is the point of the service)."""
-        reads = [np.asarray(r, np.int8) for r in reads]
-        lookups, flags = self._seed(reads)
-        per_read = self._chain(lookups)
+        with obs.span("map.batch", reads=len(reads)):
+            reads = [np.asarray(r, np.int8) for r in reads]
+            lookups, flags = self._seed(reads)
+            per_read = self._chain(lookups)
 
-        for read, cands in zip(reads, per_read):
-            for rank, cand in enumerate(cands):
-                self._submit(read, cand, rank)
+            with obs.span("map.submit") as sp:
+                for read, cands in zip(reads, per_read):
+                    for rank, cand in enumerate(cands):
+                        self._submit(read, cand, rank)
+                if obs.enabled():
+                    sp.set_metadata(pairs=sum(map(len, per_read)))
 
-        results = []
-        for read, cands, (capped, total) in zip(reads, per_read, flags):
-            if not cands:
-                status = (STATUS_SEED_CAPPED if capped > 0 and capped == total
-                          else STATUS_UNMAPPED)
-                results.append(MapResult(status=status))
-                continue
-            scored = []
-            for cand in cands:
-                res = cand.future.result()
-                ok = int(res["status"]) == 0  # xdrop may retire a junk
-                #   candidate on-device; it then scores like no hit
-                score = int(res["best_score"]) if ok else None
-                scored.append((score, cand, res))
-            alive = [(s, c, r) for s, c, r in scored if s is not None]
-            if not alive:
-                results.append(MapResult(status=STATUS_UNMAPPED,
-                                         n_candidates=len(cands)))
-                continue
-            alive.sort(key=lambda t: (-t[0], t[1].strand,
-                                      t[1].chain.diag_start))
-            s1, best, res = alive[0]
-            s2 = alive[1][0] if len(alive) > 1 else 0
-            results.append(MapResult(
-                status=STATUS_MAPPED, strand=best.strand,
-                ref_start=max(best.chain.diag_start, 0),
-                score=s1, second_score=s2,
-                mapq=_mapq(s1, s2, len(alive)),
-                chain_score=best.chain.score,
-                band=int(res["band"]),
-                window=(best.wlo, best.whi),
-                n_candidates=len(cands),
-                cigar=res.get("cigar") if self.collect_tb else None))
-        return results
+            results = []
+            for read, cands, (capped, total) in zip(reads, per_read, flags):
+                if not cands:
+                    status = (STATUS_SEED_CAPPED
+                              if capped > 0 and capped == total
+                              else STATUS_UNMAPPED)
+                    results.append(MapResult(status=status))
+                    continue
+                scored = []
+                for cand in cands:
+                    res = _await(cand.future)
+                    ok = int(res["status"]) == 0  # xdrop may retire a junk
+                    #   candidate on-device; it then scores like no hit
+                    score = int(res["best_score"]) if ok else None
+                    scored.append((score, cand, res))
+                alive = [(s, c, r) for s, c, r in scored if s is not None]
+                if not alive:
+                    results.append(MapResult(status=STATUS_UNMAPPED,
+                                             n_candidates=len(cands)))
+                    continue
+                alive.sort(key=lambda t: (-t[0], t[1].strand,
+                                          t[1].chain.diag_start))
+                s1, best, res = alive[0]
+                s2 = alive[1][0] if len(alive) > 1 else 0
+                results.append(MapResult(
+                    status=STATUS_MAPPED, strand=best.strand,
+                    ref_start=max(best.chain.diag_start, 0),
+                    score=s1, second_score=s2,
+                    mapq=_mapq(s1, s2, len(alive)),
+                    chain_score=best.chain.score,
+                    band=int(res["band"]),
+                    window=(best.wlo, best.whi),
+                    n_candidates=len(cands),
+                    cigar=res.get("cigar") if self.collect_tb else None))
+            return results
 
 
 __all__ = ["ReadMapper", "MapResult", "STATUS_MAPPED", "STATUS_UNMAPPED",

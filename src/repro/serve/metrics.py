@@ -48,7 +48,6 @@ def _percentiles(lat: np.ndarray) -> dict:
     for name, q in (("p50_ms", 50.0), ("p99_ms", 99.0)):
         out[name] = (float(np.percentile(lat, q)) * 1e3
                      if lat.size else 0.0)
-    out["mean_ms"] = float(lat.mean()) * 1e3 if lat.size else 0.0
     return out
 
 

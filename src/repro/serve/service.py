@@ -74,6 +74,7 @@ from concurrent.futures import Future, InvalidStateError
 
 import numpy as np
 
+from repro import obs
 from repro.core.batch import length_class
 from repro.core.engine import AlignmentEngine, PendingPersistent, SCALAR_KEYS
 from repro.serve.metrics import ServiceMetrics
@@ -479,30 +480,43 @@ class AlignmentService:
         in-flight groups first whenever the pipeline depth limit would
         be exceeded."""
         self.metrics.record_flush(cause)
-        if self.engine.dispatch == "persistent":
-            while len(self._inflight) >= self.max_inflight_groups:
-                self._finalize_oldest()
-            t0 = time.perf_counter()
-            pd = self.engine.enqueue_persistent(
-                [r.read for r in reqs], [r.ref for r in reqs],
-                mode=self.mode, collect_tb=self.collect_tb)
-            self._note_enqueue(pd, time.perf_counter() - t0)
-            self.metrics.record_dispatch(pd.num_real, pd.num_slots)
-            self._inflight.append((pd, reqs))
-            return
-        groups = self.engine.plan([len(r.read) for r in reqs],
-                                  [len(r.ref) for r in reqs])
-        for g in groups:
-            while len(self._inflight) >= self.max_inflight_groups:
-                self._finalize_oldest()
-            members = [reqs[i] for i in g.indices]
-            t0 = time.perf_counter()
-            pd = self.engine.enqueue_group(
-                [r.read for r in members], [r.ref for r in members],
-                g.spec, mode=self.mode, collect_tb=self.collect_tb)
-            self._note_enqueue(pd, time.perf_counter() - t0)
-            self.metrics.record_dispatch(pd.num_real, pd.num_slots)
-            self._inflight.append((pd, members))
+        stats = {}
+        if obs.enabled():
+            now = self._time()
+            stats = dict(cause=cause, pairs=len(reqs), wait_us_sum=round(
+                1e6 * sum(now - r.t_submit for r in reqs)))
+        with obs.span("serve.flush", **stats):
+            if self.engine.dispatch == "persistent":
+                while len(self._inflight) >= self.max_inflight_groups:
+                    self._finalize_oldest()
+                t0 = time.perf_counter()
+                with obs.build_span("serve.enqueue", pairs=len(reqs)) as sp:
+                    pd = self.engine.enqueue_persistent(
+                        [r.read for r in reqs], [r.ref for r in reqs],
+                        mode=self.mode, collect_tb=self.collect_tb)
+                    if obs.enabled():
+                        sp.set_metadata(slots=pd.num_slots, band=max(
+                            g.spec.band for g in pd.groups))
+                self._note_enqueue(pd, time.perf_counter() - t0)
+                self.metrics.record_dispatch(pd.num_real, pd.num_slots)
+                self._inflight.append((pd, reqs))
+                return
+            groups = self.engine.plan([len(r.read) for r in reqs],
+                                      [len(r.ref) for r in reqs])
+            for g in groups:
+                while len(self._inflight) >= self.max_inflight_groups:
+                    self._finalize_oldest()
+                members = [reqs[i] for i in g.indices]
+                t0 = time.perf_counter()
+                with obs.build_span("serve.enqueue", pairs=len(members),
+                                    band=g.spec.band) as sp:
+                    pd = self.engine.enqueue_group(
+                        [r.read for r in members], [r.ref for r in members],
+                        g.spec, mode=self.mode, collect_tb=self.collect_tb)
+                    sp.set_metadata(slots=pd.num_slots)
+                self._note_enqueue(pd, time.perf_counter() - t0)
+                self.metrics.record_dispatch(pd.num_real, pd.num_slots)
+                self._inflight.append((pd, members))
 
     def _note_enqueue(self, pd, enqueue_s: float) -> None:
         if self._autotuner is not None:
@@ -512,42 +526,44 @@ class AlignmentService:
         # Peek, don't pop: if finalize raises, the group must still be
         # in _inflight so _fail_everything can fail its futures.
         pd, members = self._inflight[0]
-        fetch_stats: dict = {}
-        t0 = time.perf_counter()
-        if isinstance(pd, PendingPersistent):
-            merged = self.engine.finalize_persistent(pd, stats=fetch_stats)
-        else:
-            merged = self.engine.finalize_group(pd, stats=fetch_stats)
-        finalize_s = time.perf_counter() - t0
-        self._inflight.popleft()
-        if self._autotuner is not None:
-            self._autotuner.note(pd.signature,
-                                 getattr(pd, "_enqueue_s", finalize_s),
-                                 finalize_s)
-            self.max_inflight_groups = self._autotuner.depth()
-        now = self._time()
-        latencies = []
-        for i, req in enumerate(members):
-            res = {key: merged[key][i] for key in SCALAR_KEYS}
-            res["band"] = (merged["band"][i] if "band" in merged
-                           else pd.spec.band)
-            if self.collect_tb:
-                res["cigar"] = merged["cigars"][i]
-            try:
-                req.future.set_result(res)
-            except InvalidStateError:
-                # A failover raced us: the router already resolved this
-                # future (e.g. handed off and then failed it when no
-                # replica would take it). The client saw exactly one
-                # resolution either way; don't let the race kill a
-                # healthy dispatcher.
-                pass
-            latencies.append(now - req.t_submit)
-        self.metrics.record_results(
-            latencies, fetch_stats.get("fetched_bytes", 0),
-            priorities=[req.priority for req in members],
-            statuses=[int(merged["status"][i])
-                      for i in range(len(members))])
+        with obs.build_span("serve.finalize", pairs=len(members)):
+            fetch_stats: dict = {}
+            t0 = time.perf_counter()
+            if isinstance(pd, PendingPersistent):
+                merged = self.engine.finalize_persistent(pd,
+                                                         stats=fetch_stats)
+            else:
+                merged = self.engine.finalize_group(pd, stats=fetch_stats)
+            finalize_s = time.perf_counter() - t0
+            self._inflight.popleft()
+            if self._autotuner is not None:
+                self._autotuner.note(pd.signature,
+                                     getattr(pd, "_enqueue_s", finalize_s),
+                                     finalize_s)
+                self.max_inflight_groups = self._autotuner.depth()
+            now = self._time()
+            latencies = []
+            for i, req in enumerate(members):
+                res = {key: merged[key][i] for key in SCALAR_KEYS}
+                res["band"] = (merged["band"][i] if "band" in merged
+                               else pd.spec.band)
+                if self.collect_tb:
+                    res["cigar"] = merged["cigars"][i]
+                try:
+                    req.future.set_result(res)
+                except InvalidStateError:
+                    # A failover raced us: the router already resolved
+                    # this future (e.g. handed off and then failed it
+                    # when no replica would take it). The client saw
+                    # exactly one resolution either way; don't let the
+                    # race kill a healthy dispatcher.
+                    pass
+                latencies.append(now - req.t_submit)
+            self.metrics.record_results(
+                latencies, fetch_stats.get("fetched_bytes", 0),
+                priorities=[req.priority for req in members],
+                statuses=[int(merged["status"][i])
+                          for i in range(len(members))])
 
     def _fail_everything(self, pending: list[_Request],
                          exc: BaseException) -> None:
