@@ -3,9 +3,10 @@
 One client maps the pool's reads in mini-batches of the configuration's
 `minibatch_bases` and starts the next when the last returns; the window
 ends when the last batch started inside it returns. Set-up builds the
-reference and its minimizer index from the seed, simulates the pool,
-and maps warm-up batches of the same size from a slice of its own until
-a pass builds no new program (or the mix's cap).
+reference and its minimizer index from the seed, simulates the pool
+(in seeded chunks, so that a larger pool keeps the reads of a smaller
+one), and maps warm-up batches of the same size from a slice of its
+own until a pass builds no new program (or the mix's cap).
 
 The service behind the mapper is wrapped so that every alignment it is
 asked for in the window is kept with its answer: those are what the
@@ -61,9 +62,10 @@ def run(ctx: harness.Context) -> dict:
 
     index.lookup = timed_lookup
     batch = int(cfg["minibatch_bases"]) // int(tr["read_len"])
-    spans = np.full(int(tr["pool_reads"]), int(tr["read_len"]))
-    pool = simulate.sample_reads(genome, spans, tr["rates"], tr["rc_prob"],
-                                 ctx.rng("pool"))
+    pool = simulate.chunked_reads(genome, int(tr["pool_reads"]),
+                                  int(tr["pool_chunk_reads"]),
+                                  int(tr["read_len"]), tr["rates"],
+                                  tr["rc_prob"], ctx.rng)
     warm = simulate.sample_reads(
         genome, np.full(batch * int(tr["warmup_passes"]), tr["read_len"]),
         tr["rates"], tr["rc_prob"], ctx.rng("warmup"))

@@ -97,6 +97,24 @@ def sample_reads(genome: np.ndarray, spans, rates: dict, rc_prob: float,
     return ReadBlock(reads=reads, loci=loci, spans=spans, strands=strands)
 
 
+def chunked_reads(genome: np.ndarray, count: int, chunk: int, read_len: int,
+                  rates: dict, rc_prob: float, rng_of) -> ReadBlock:
+    """A pool of `count` reads of `read_len` bases, drawn `chunk` at a
+    time: chunk 0 from the generator `rng_of("pool")`, chunk k > 0 from
+    `rng_of(f"pool.{k}")`. A larger pool of the same chunk size
+    therefore starts with the same reads, loci and strands."""
+    parts = []
+    for k, lo in enumerate(range(0, int(count), int(chunk))):
+        size = min(int(chunk), int(count) - lo)
+        parts.append(sample_reads(genome, np.full(size, int(read_len)),
+                                  rates, rc_prob,
+                                  rng_of("pool" if k == 0 else f"pool.{k}")))
+    return ReadBlock(reads=[r for p in parts for r in p.reads],
+                     loci=np.concatenate([p.loci for p in parts]),
+                     spans=np.concatenate([p.spans for p in parts]),
+                     strands=np.concatenate([p.strands for p in parts]))
+
+
 def stratified_spans(count: int, lo: int, hi: int, block: int,
                      rng: np.random.Generator) -> np.ndarray:
     """Span lengths uniform over [lo, hi], stratified per block: every

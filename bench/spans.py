@@ -8,9 +8,11 @@ inside the harness's window:
 - `self_intervals` splits each host line (thread) into the intervals in
   which each span is the innermost one open, so a span's self time is
   its duration minus the child spans on its line that it covers;
-- `idle_by_stage` attributes each device's idle time in the window to
-  the stages the host was working in while the device idled (a span
-  with the stat `wait=1` is a thread blocked, not working).
+- `stage_seconds` splits any intervals of the window by the stages the
+  host was working in (a span with the stat `wait=1` is a thread
+  blocked, not working); `idle_by_stage` applies it to each device's
+  idle time, and the trace reduction to each long idle gap;
+- `self_ms_per_read` is what the per-read stage metrics read.
 
 Times are nanoseconds on the trace's clock unless a name says seconds.
 """
@@ -105,36 +107,53 @@ def _idle(reduced, device: int, window) -> list[tuple]:
     return [(s, e) for s, e in zip(edges[0::2], edges[1::2]) if e > s]
 
 
-def idle_by_stage(reduced, spans, window) -> dict[str, float]:
-    """Device idle seconds in the window, averaged over devices, keyed
-    by the working stages: the innermost non-wait spans open on any
-    host line at the time, short names joined by "+", or UNEXPLAINED
-    when every host line was waiting or in no span."""
-    points = []
+def stage_marks(spans) -> list[tuple]:
+    """[(time, +1 or -1, short stage name)]: where each working (not
+    waiting) innermost span opens and closes, for `stage_seconds`."""
+    marks = []
     for _, s, e, name, wait in self_intervals(spans):
         if not wait:
             short = name[len(PREFIX):]
-            points += [(s, 1, short), (e, -1, short)]
+            marks += [(s, 1, short), (e, -1, short)]
+    return marks
+
+
+def stage_seconds(marks, intervals) -> dict[str, float]:
+    """Seconds of the disjoint `intervals` keyed by the working stages:
+    the innermost non-wait spans open on any host line at the time,
+    short names joined by "+", or UNEXPLAINED when every host line was
+    waiting or in no span. `marks` is what `stage_marks` gives."""
+    marks = list(marks)
+    for s, e in intervals:
+        marks += [(s, 1, None), (e, -1, None)]
+    marks.sort(key=lambda m: (m[0], m[1]))
+    open_: dict[str, int] = {}
+    inside = 0
+    t_prev = None
+    out: dict[str, float] = {}
+    for t, step, name in marks:
+        if inside and t_prev is not None and t > t_prev:
+            key = "+".join(sorted(n for n, c in open_.items() if c))
+            key = key or UNEXPLAINED
+            out[key] = out.get(key, 0.0) + (t - t_prev) * 1e-9
+        if name is None:
+            inside += step
+        else:
+            open_[name] = open_.get(name, 0) + step
+        t_prev = t
+    return out
+
+
+def idle_by_stage(reduced, spans, window) -> dict[str, float]:
+    """Device idle seconds in the window, averaged over devices, keyed
+    by the working stages as `stage_seconds` keys them."""
+    marks = stage_marks(spans)
     devices = range(len(reduced["devices"]))
     out: dict[str, float] = {}
     for device in devices:
-        marks = list(points)
-        for s, e in _idle(reduced, device, window):
-            marks += [(s, 1, None), (e, -1, None)]
-        marks.sort(key=lambda m: (m[0], m[1]))
-        open_: dict[str, int] = {}
-        idle = 0
-        t_prev = None
-        for t, step, name in marks:
-            if idle and t_prev is not None and t > t_prev:
-                key = "+".join(sorted(n for n, c in open_.items() if c))
-                key = key or UNEXPLAINED
-                out[key] = out.get(key, 0.0) + (t - t_prev) * 1e-9
-            if name is None:
-                idle += step
-            else:
-                open_[name] = open_.get(name, 0) + step
-            t_prev = t
+        for key, sec in stage_seconds(
+                marks, _idle(reduced, device, window)).items():
+            out[key] = out.get(key, 0.0) + sec
     return {k: v / max(len(devices), 1) for k, v in out.items()}
 
 
@@ -147,3 +166,14 @@ def idle_explained_share(reduced, spans, window) -> float | None:
         return None
     return 1.0 - by_stage.get(UNEXPLAINED, 0.0) / total
 
+
+def self_ms_per_read(run, name: str) -> float | None:
+    """Self time of the spans called `name` in a traced run's window, in
+    milliseconds per completed read; None where the run was not traced
+    or no such span ran in its window."""
+    if run.trace is None:
+        return None
+    sec = self_seconds(run.trace["spans"]).get(name)
+    if sec is None:
+        return None
+    return sec * 1e3 / max(run.record["reads"], 1)

@@ -1,6 +1,7 @@
 """The simulator's error rates, and determinism of every input in the
 seed."""
 
+import json
 import pathlib
 import sys
 
@@ -62,3 +63,65 @@ def test_span_blocks_hold_the_same_lengths_in_seeded_orders():
     assert sorted(a[:512]) == sorted(b[:512]) == sorted(a[512:])
     assert not np.array_equal(a, b)
     assert a.min() >= 2000 and a.max() <= 10000
+
+
+#: The Illumina mapping mix's pool: its size and its chunk, as the
+#: traffic file gives them.
+MAP_MIX = json.loads((pathlib.Path(__file__).resolve().parents[1] / "traffic"
+                      / "illumina_map_closed.json").read_text())
+
+
+@pytest.fixture(scope="module", params=[3000000001, 2**31 + 11])
+def pool(request):
+    seed = request.param
+    t = MAP_MIX
+    genome = simulate.random_genome(4_641_652, simulate.rng_for(seed, "g"))
+    chunked = simulate.chunked_reads(
+        genome, t["pool_reads"], t["pool_chunk_reads"], t["read_len"],
+        t["rates"], t["rc_prob"], lambda s: simulate.rng_for(seed, s))
+    # The pool as one draw of a single chunk from the stream "pool".
+    single = simulate.sample_reads(
+        genome, np.full(t["pool_chunk_reads"], t["read_len"]), t["rates"],
+        t["rc_prob"], simulate.rng_for(seed, "pool"))
+    return chunked, single
+
+
+def test_the_pool_lasts_its_size_in_chunks(pool):
+    chunked, _ = pool
+    assert MAP_MIX["pool_reads"] == 4 * MAP_MIX["pool_chunk_reads"] == 163_840
+    assert len(chunked) == len(chunked.loci) == len(chunked.strands) == \
+        len(chunked.spans) == 163_840
+    assert all(len(r) > 0 for r in chunked.reads)
+
+
+def test_the_first_chunk_is_the_single_draw_bit_for_bit(pool):
+    chunked, single = pool
+    n = len(single)
+    assert all(np.array_equal(a, b) and a.dtype == b.dtype
+               for a, b in zip(chunked.reads[:n], single.reads))
+    assert np.array_equal(chunked.loci[:n], single.loci)
+    assert np.array_equal(chunked.strands[:n], single.strands)
+    assert np.array_equal(chunked.spans[:n], single.spans)
+
+
+def test_later_chunks_come_from_streams_of_their_own(pool):
+    chunked, _ = pool
+    n = MAP_MIX["pool_chunk_reads"]
+    first = chunked.loci[:n]
+    for k in range(1, 4):
+        later = chunked.loci[k * n:(k + 1) * n]
+        assert np.mean(later == first) < 0.01
+        assert not any(np.array_equal(a, b) for a, b in
+                       zip(chunked.reads[k * n:k * n + 100],
+                           chunked.reads[:100]))
+
+
+def test_a_pool_smaller_than_a_chunk_is_one_draw():
+    rng = simulate.rng_for(9, "g")
+    genome = simulate.random_genome(20_000, rng)
+    small = simulate.chunked_reads(genome, 300, 1000, 100, PACBIO, 0.5,
+                                   lambda s: simulate.rng_for(9, s))
+    one = simulate.sample_reads(genome, np.full(300, 100), PACBIO, 0.5,
+                                simulate.rng_for(9, "pool"))
+    assert len(small) == 300
+    assert np.array_equal(small.loci, one.loci)

@@ -2,12 +2,14 @@
 
 import pathlib
 import sys
+import types
 
 import pytest
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
+import loader  # noqa: E402
 import spans  # noqa: E402
 import trace_reduce  # noqa: E402
 
@@ -154,3 +156,99 @@ def test_a_gap_covered_only_by_a_wait_span_is_unexplained(traced):
         {"none": 60 * US / 2})
     assert spans.idle_explained_share(reduced, waits, window) == 0.0
 
+
+
+# The readers' trace, built from (line, name, start us, end us, stats)
+# with the window at 0-100 us. Dispatcher (line 2): a finalize
+# -10..8 (builds 3) holding a fetch -8..5, both crossing the window's
+# start; a flush 15-45 holding an enqueue 20-40 (builds 2) that holds a
+# fetch 30-34; a finalize 50-70 (builds 1) holding a fetch 52-60 and a
+# decode 60-65; an enqueue 90-110 (builds 4) crossing the end; a
+# finalize 120-130 (builds 5) outside. Client (line 1): bench.map_batch
+# 5-95; map.batch 5-95 holding map.submit 5-45 and map.await 45-95 (a
+# wait). The device works 40-50 and 70-75.
+HOST = [(1, "bench.window", 0, 100, {}),
+        (1, "bench.map_batch", 5, 95, {}),
+        (1, "rapidx.map.batch", 5, 95, {}),
+        (1, "rapidx.map.submit", 5, 45, {}),
+        (1, "rapidx.map.await", 45, 95, {"wait": 1}),
+        (2, "rapidx.serve.finalize", -10, 8, {"builds": 3}),
+        (2, "rapidx.serve.fetch", -8, 5, {}),
+        (2, "rapidx.serve.flush", 15, 45, {}),
+        (2, "rapidx.serve.enqueue", 20, 40, {"builds": 2}),
+        (2, "rapidx.serve.fetch", 30, 34, {}),
+        (2, "rapidx.serve.finalize", 50, 70, {"builds": 1}),
+        (2, "rapidx.serve.fetch", 52, 60, {}),
+        (2, "rapidx.serve.decode", 60, 65, {}),
+        (2, "rapidx.serve.enqueue", 90, 110, {"builds": 4}),
+        (2, "rapidx.serve.finalize", 120, 130, {"builds": 5})]
+DEVICE = [(40, 50), (70, 75)]
+#: The line's time stamp lies this far before the window (us), so that
+#: every offset is positive.
+T0 = 20
+
+
+def text_proto(host, device) -> str:
+    names = sorted({h[1] for h in host})
+    stats = sorted({k for h in host for k in h[4]})
+    lines = {}
+    for line, name, s, e, st in host:
+        ev = (f"events {{ metadata_id: {names.index(name) + 1} "
+              f"offset_ps: {(s + T0) * 1000000} "
+              f"duration_ps: {(e - s) * 1000000}")
+        for k, v in st.items():
+            ev += f" stats {{ metadata_id: {stats.index(k) + 1} " \
+                  f"int64_value: {v} }}"
+        lines.setdefault(line, []).append(ev + " }")
+    out = 'planes { id: 1 name: "/host:CPU"\n'
+    for line, evs in lines.items():
+        out += (f'lines {{ id: {line} name: "python" timestamp_ns: 1000\n'
+                + "\n".join(evs) + "}\n")
+    out += "".join(f'event_metadata {{ key: {i + 1} value {{ id: {i + 1} '
+                   f'name: "{n}" }} }}\n' for i, n in enumerate(names))
+    out += "".join(f'stat_metadata {{ key: {i + 1} value {{ id: {i + 1} '
+                   f'name: "{n}" }} }}\n' for i, n in enumerate(stats))
+    out += '}\nplanes { id: 2 name: "/device:TPU:0"\n'
+    out += 'lines { id: 1 name: "XLA Ops" timestamp_ns: 1000\n'
+    out += "".join(f"events {{ metadata_id: 1 offset_ps: {(s + T0) * 1000000}"
+                   f" duration_ps: {(e - s) * 1000000} }}\n"
+                   for s, e in device)
+    return out + ('}\nevent_metadata { key: 1 value { id: 1 '
+                  'name: "fusion.1" } }\n}\n')
+
+
+@pytest.fixture(scope="module")
+def served():
+    from jax.profiler import ProfileData
+    reduced = trace_reduce.reduce(
+        ProfileData.from_text_proto(text_proto(HOST, DEVICE)))
+    return types.SimpleNamespace(trace=reduced, record={"reads": 2})
+
+
+@pytest.mark.parametrize("metric,want", [
+    # (20-40 less its fetch 30-34) + 90-100 clipped, over 2 reads.
+    ("enqueue_ms_per_read", (16 + 10) * US * 1e3 / 2),
+    # 0-5 clipped + 30-34 + 52-60, over 2 reads.
+    ("fetch_ms_per_read", (5 + 4 + 8) * US * 1e3 / 2),
+    # The spans that overlap the window, the one crossing its start whole.
+    ("builds_in_finalize.closed", 3 + 1),
+])
+def test_the_span_readers(served, metric, want):
+    reader = loader.load_reader(metric)
+    assert reader.read(served) == pytest.approx(want)
+    untraced = types.SimpleNamespace(trace=None, record={"reads": 2})
+    assert reader.read(untraced) is None
+    silent = types.SimpleNamespace(trace={**served.trace, "spans": []},
+                                   record={"reads": 2})
+    assert reader.read(silent) is None
+
+
+def test_idle_gaps_are_named_by_the_stages_working_in_them(served):
+    # Idle 0-40: map.submit+serve.enqueue holds 16 us of it, more than
+    # any other mix. Idle 75-100: no stage works in 75-90 (the client
+    # waits), more than serve.enqueue's 90-100, so the harness's span.
+    # Idle 50-70: the client waits; serve.fetch works 8 us of it.
+    assert served.trace["gaps"] == [
+        ("map.submit+serve.enqueue", pytest.approx(40 * US)),
+        ("bench.map_batch", pytest.approx(25 * US)),
+        ("serve.fetch", pytest.approx(20 * US))]

@@ -7,8 +7,12 @@ its busy time, and their durations summed by name are the device time
 per op. An op's label for matching is its name with its stats (XLA
 puts the op's origin, such as `jit(decode_packed_tb)/while`, there).
 The gaps between busy intervals are the device's idle time;
-each is labelled with the harness span (`bench.*`) that overlapped it
-most on the host, or `none`.
+each is labelled with the program's working stages that held most of
+it (`spans.stage_seconds`, such as `map.submit+serve.enqueue`), or,
+where no program stage was working in most of it, with the harness
+span (`bench.*`) that overlapped it most on the host, or `none`. The
+program's own host spans (`rapidx.*`) of the window are kept for the
+metrics that read them (`spans.py`).
 """
 
 from __future__ import annotations
@@ -16,6 +20,8 @@ from __future__ import annotations
 import json
 import pathlib
 import re
+
+import spans
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OP_LINE = "XLA Ops"
@@ -66,10 +72,12 @@ def reduce(profile) -> dict:
     "op_s": {op name, cut to SHORT: seconds summed over devices},
     "events": [(device index, start ns, end ns, label)] inside the
     window,
-    "gaps": [(label, seconds)] longest first, at most TOP}. Times are in
-    seconds; the trace's are in nanoseconds."""
+    "gaps": [(label, seconds)] longest first, at most TOP,
+    "spans": the program's host spans clipped to the window, as
+    `spans.program_spans` gives them}. Times are in seconds; the trace's
+    are in nanoseconds."""
     window = None
-    spans = []
+    harness_spans = []
     devices = []
     for plane in profile.planes:
         if DEVICE_PLANE.match(plane.name):
@@ -80,7 +88,7 @@ def reduce(profile) -> dict:
                 if ev.name == WINDOW_SPAN:
                     window = (ev.start_ns, ev.end_ns)
                 elif ev.name.startswith("bench."):
-                    spans.append((ev.start_ns, ev.end_ns, ev.name))
+                    harness_spans.append((ev.start_ns, ev.end_ns, ev.name))
     if window is None:
         raise ValueError(f"no {WINDOW_SPAN} span in the trace")
     w0, w1 = window
@@ -106,15 +114,22 @@ def reduce(profile) -> dict:
             if e > s:
                 gaps.append((s, e))
     gaps.sort(key=lambda g: g[0] - g[1])
-    labelled = [(_label(s, e, spans), (e - s) * 1e-9)
+    stages = spans.program_spans(profile, window)
+    marks = spans.stage_marks(stages)
+    labelled = [(_label(s, e, marks, harness_spans), (e - s) * 1e-9)
                 for s, e in gaps[:TOP]]
     return {"window_s": (w1 - w0) * 1e-9, "devices": out_devices,
-            "op_s": op_s, "events": events, "gaps": labelled}
+            "op_s": op_s, "events": events, "gaps": labelled,
+            "spans": stages}
 
 
-def _label(s: float, e: float, spans) -> str:
+def _label(s: float, e: float, marks, harness_spans) -> str:
+    by_stage = spans.stage_seconds(marks, [(s, e)])
+    key = max(by_stage, key=by_stage.get, default=spans.UNEXPLAINED)
+    if key != spans.UNEXPLAINED:
+        return key
     best, label = 0.0, "none"
-    for a, b, name in spans:
+    for a, b, name in harness_spans:
         overlap = min(b, e) - max(a, s)
         if overlap > best:
             best, label = overlap, name
