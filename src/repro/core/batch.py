@@ -205,9 +205,10 @@ def enqueue_dispatch(run, q_pad, r_pad, n, m, *, capacity: int):
     """Enqueue one padded single-length-class group on the device.
 
     `run` is a fully-bound backend callable `(q, r, n, m) -> result
-    dict` — a partial over `backend.run` or a jit'd shard_map program
-    (the engine's mesh path, where each slice spans one capacity block
-    per mesh shard). Executes in fixed-capacity slices (one XLA program
+    dict` — the engine's cached jit'd program for the group's dispatch
+    signature (`AlignmentEngine.runner`; on a mesh each slice spans one
+    capacity block per mesh shard), or a partial over `backend.run`
+    (`run_dispatch`). Executes in fixed-capacity slices (one XLA program
     per (bucket shape, band, t_max)) and returns the raw per-slice
     result dicts as *device arrays* — nothing is materialised on the
     host, so JAX's async dispatch keeps the device busy while the

@@ -14,10 +14,12 @@ engine
   2. dispatches groups through a depth-1 lookahead pipeline on the
      selected backend ('reference' = vmapped lax.scan, 'pallas' = the
      in-VMEM wavefront kernel, 'auto' = pallas on TPU else reference;
-     see `core.backends`): group k+1's capacity slices are enqueued
-     on-device before group k is materialised, so JAX async dispatch
-     keeps the device computing group k+1 while the host fetches and
-     CIGAR-decodes group k — with at most two groups' buffers live,
+     see `core.backends`), each slice through the engine's jit'd
+     program for its dispatch signature (`runner`, built once): group
+     k+1's capacity slices are enqueued on-device before group k is
+     materialised, so JAX async dispatch keeps the device computing
+     group k+1 while the host fetches and CIGAR-decodes group k — with
+     at most two groups' buffers live,
   3. with `mesh=`, shards each dispatch slice over the mesh's data axes
      via `shard_map` (paper Fig. 6(a) tile level: alignment needs no
      inter-tile communication, so the lowered program has zero
@@ -43,7 +45,6 @@ stream is `repro.serve.AlignmentService`, which drives the same
 from __future__ import annotations
 
 import dataclasses
-import functools
 import os
 import pathlib
 
@@ -324,33 +325,24 @@ class AlignmentEngine:
                            dtype=np.int64))
 
     # ------------------------------------------------------------------
-    # Mesh path: one jit'd shard_map program per dispatch signature.
+    # One jit'd dispatch program per dispatch signature, both paths.
     # ------------------------------------------------------------------
-    def sharded_runner(self, *, band: int, collect_tb: bool = False,
-                       mode: str = "global", t_max: int | None = None,
-                       decode: str = "host"):
-        """The jit'd shard_map'd backend program for one dispatch
-        signature (cached per engine). The batch dimension of every
-        argument shards over the mesh's `batch_axes`; because the
-        backend contract is jax-traceable and alignment is
-        embarrassingly parallel, the lowered program contains zero
-        collectives (asserted by tests/test_distributed.py) — including
-        with decode="device", where the lockstep traceback walker is
-        fused under the same shard_map (the walk is per-pair, so it
-        shards with the batch and needs no communication either)."""
-        if self.mesh is None:
-            raise ValueError("sharded_runner requires AlignmentEngine("
-                             "mesh=...)")
-        key = (band, collect_tb, mode, t_max, decode, self.xdrop)
+    def runner(self, *, band: int, collect_tb: bool = False,
+               mode: str = "global", t_max: int | None = None,
+               decode: str = "host"):
+        """The jit'd backend program for one dispatch signature, cached
+        per engine (xdrop, cell_dtype, adaptive and sc are fixed per
+        engine; shapes are left to jit's own cache). Without a mesh it
+        is `jit(banded_dispatch)`: the backend's kernel, its layout ops
+        and, with decode="device", the lockstep traceback walker in one
+        program, built once and then dispatched per slice. With a mesh
+        it is `sharded_runner`'s shard_map program."""
+        key = (band, collect_tb, mode, t_max, decode)
         fn = self._runners.get(key)
         if fn is None:
             import jax
-            from jax.sharding import PartitionSpec as P
-            from repro.core.distributed import shard_map
 
-            spec = P(self.batch_axes)
-
-            def local_align(q, r, n, m):
+            def banded_dispatch(q, r, n, m):
                 return self.backend.run(q, r, n, m, sc=self.sc, band=band,
                                         adaptive=self.adaptive,
                                         collect_tb=collect_tb, mode=mode,
@@ -358,11 +350,43 @@ class AlignmentEngine:
                                         cell_dtype=self.cell_dtype,
                                         xdrop=self.xdrop)
 
-            fn = jax.jit(shard_map(local_align, mesh=self.mesh,
-                                   in_specs=(spec, spec, spec, spec),
-                                   out_specs=spec))
+            if self.mesh is None:
+                fn = jax.jit(banded_dispatch)
+            else:
+                from jax.sharding import PartitionSpec as P
+                from repro.core.distributed import shard_map
+
+                spec = P(self.batch_axes)
+
+                # The mesh program keeps its name: the device trace's
+                # kernel pattern (bench/trace_names.json) reads it, and
+                # would read a whole one-device program under it too.
+                def local_align(q, r, n, m):
+                    return banded_dispatch(q, r, n, m)
+
+                fn = jax.jit(shard_map(local_align, mesh=self.mesh,
+                                       in_specs=(spec, spec, spec, spec),
+                                       out_specs=spec))
             self._runners[key] = fn
         return fn
+
+    def sharded_runner(self, *, band: int, collect_tb: bool = False,
+                       mode: str = "global", t_max: int | None = None,
+                       decode: str = "host"):
+        """The jit'd shard_map'd backend program for one dispatch
+        signature (`runner` on an engine with a mesh). The batch
+        dimension of every argument shards over the mesh's
+        `batch_axes`; because the backend contract is jax-traceable and
+        alignment is embarrassingly parallel, the lowered program
+        contains zero collectives (asserted by tests/test_distributed.py)
+        — including with decode="device", where the lockstep traceback
+        walker is fused under the same shard_map (the walk is per-pair,
+        so it shards with the batch and needs no communication either)."""
+        if self.mesh is None:
+            raise ValueError("sharded_runner requires AlignmentEngine("
+                             "mesh=...)")
+        return self.runner(band=band, collect_tb=collect_tb, mode=mode,
+                           t_max=t_max, decode=decode)
 
     # ------------------------------------------------------------------
     # Padded single-length-class path (jax arrays in, jax arrays out).
@@ -386,16 +410,9 @@ class AlignmentEngine:
             band = adaptive_bandwidth(L, default_base_bandwidth(
                 L, self.base_bandwidth), cap=self.band_cap)
         _check_t_max(t_max, n, m)
-        if self.mesh is not None:
-            fn = self.sharded_runner(band=band, collect_tb=collect_tb,
-                                     mode=mode, t_max=t_max, decode=decode)
-            return fn(q_pad, r_pad, n, m)
-        return self.backend.run(q_pad, r_pad, n, m, sc=self.sc, band=band,
-                                adaptive=self.adaptive,
-                                collect_tb=collect_tb, mode=mode,
-                                t_max=t_max, decode=decode,
-                                cell_dtype=self.cell_dtype,
-                                xdrop=self.xdrop)
+        fn = self.runner(band=band, collect_tb=collect_tb, mode=mode,
+                         t_max=t_max, decode=decode)
+        return fn(q_pad, r_pad, n, m)
 
     # ------------------------------------------------------------------
     # Group-at-a-time pipeline primitives (the service's driving API).
@@ -421,16 +438,8 @@ class AlignmentEngine:
         t_max = spec.t_max if self.trim else None
         q_pad, r_pad, n, m = pad_group(
             reads, refs, spec, pad_multiple=spec.capacity * self.num_shards)
-        if self.mesh is not None:
-            run = self.sharded_runner(
-                band=spec.band, collect_tb=collect_tb, mode=mode,
-                t_max=t_max, decode=self.decode)
-        else:
-            run = functools.partial(
-                self.backend.run, sc=self.sc, band=spec.band,
-                adaptive=self.adaptive, collect_tb=collect_tb,
-                mode=mode, t_max=t_max, decode=self.decode,
-                cell_dtype=self.cell_dtype, xdrop=self.xdrop)
+        run = self.runner(band=spec.band, collect_tb=collect_tb, mode=mode,
+                          t_max=t_max, decode=self.decode)
         outs = enqueue_dispatch(run, q_pad, r_pad, n, m,
                                 capacity=spec.capacity * self.num_shards)
         return PendingDispatch(spec=spec, n=n, m=m, outs=outs,

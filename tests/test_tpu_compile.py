@@ -127,3 +127,23 @@ def test_chain_program_compiles(one_chip):
     pos = _spec(one_chip, (16, p.anchors_cap), jnp.int32)
     valid = _spec(one_chip, (16, p.anchors_cap), jnp.bool_)
     fn.lower(pos, pos, valid).compile()
+
+
+def test_engine_dispatch_program_compiles(one_chip):
+    """The engine's one-device dispatch program for the short-read class:
+    the kernel and the traceback walker (a loop that carries the packed
+    u8 plane) in one compiled program."""
+    import re
+
+    from repro.core.engine import AlignmentEngine
+
+    L, band, t_max = SHORT
+    eng = AlignmentEngine(backend="pallas", capacity=32,
+                          backend_opts={"interpret": False})
+    fn = eng.runner(band=band, collect_tb=True, mode="semiglobal",
+                    t_max=t_max, decode="device")
+    seq = _spec(one_chip, (32, L), jnp.int8)
+    lens = _spec(one_chip, (32,), jnp.int32)
+    text = fn.lower(seq, seq, lens, lens).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%while\.\d+ = \([^=]*u8\[", text)
